@@ -7,10 +7,12 @@ Per (arch x shape x mesh) cell:
 plus the dominant term, MODEL_FLOPS (6ND / 2ND), and the
 MODEL_FLOPS / HLO_FLOPs usefulness ratio (catches remat/redundancy waste).
 
-Hardware constants: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM,
-~50 GB/s/link ICI.  Methodology notes: the per-device numbers come from the
-CPU-backend SPMD module (bf16 dots promoted to f32 -> bytes are an upper
-bound; see launch/hlo_analysis.py docstring).
+Hardware constants: the TPU v5e row of the one sourced peak table,
+``repro.kernels.tiling.DEVICE_PEAKS`` (197 TFLOP/s bf16, 394 TOP/s int8,
+819 GB/s HBM, ~50 GB/s/link ICI).  Everything here is a modelled v5e
+prediction, never a device measurement.  Methodology notes: the per-device
+numbers come from the CPU-backend SPMD module (bf16 dots promoted to f32 ->
+bytes are an upper bound; see launch/hlo_analysis.py docstring).
 
 Usage: PYTHONPATH=src python -m benchmarks.roofline [--mesh pod]
 """
@@ -20,11 +22,14 @@ import argparse
 import json
 import os
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-ICI_BW = 50e9
-HBM_PER_CHIP = 16e9          # v5e
-INT8_PEAK_FLOPS = 394e12     # v5e MXU: int8 doubles bf16 MACs/cycle
+from repro.kernels.tiling import DEVICE_PEAKS, MODELLED_KIND
+
+V5E = DEVICE_PEAKS[MODELLED_KIND]
+PEAK_FLOPS = V5E['bf16_flops']
+HBM_BW = V5E['hbm_bytes_per_s']
+ICI_BW = V5E['ici_link_bytes_per_s']
+HBM_PER_CHIP = V5E['hbm_bytes']
+INT8_PEAK_FLOPS = V5E['int8_ops']    # MXU: int8 doubles bf16 MACs/cycle
 
 
 def int8_serving_roofline(plan_layers: dict) -> dict:

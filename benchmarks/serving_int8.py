@@ -144,6 +144,8 @@ def _breakdown(m, x, iters, use_pallas):
 
 
 def main():
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     from repro.configs.cnn import (MOBILENET_SMALL_CIFAR, RESNET8_CIFAR,
                                    VGG8_CIFAR)
     from repro.core.export import export_cnn

@@ -296,6 +296,8 @@ def run_chaos(args, fam, cfg, params, xs, calib, threshold, stage_costs_us,
 
 
 def main():
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     from repro.configs.cnn import CNN_REGISTRY
     from repro.core.export import calibrate_exit_threshold, export_cnn
     from repro.core.family import CNNFamily

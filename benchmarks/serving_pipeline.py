@@ -16,9 +16,10 @@ costs:
 
 Methodology matches serving_load.py: median per-stage costs at the fixed
 slot geometry drive a simulated event clock while the data path executes
-for real — here on N **forced host devices** (the benchmark re-execs
-itself under ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` when
-the process has fewer devices than requested).  Every sampled request's
+for real — on N accelerator devices, or on N **forced host devices**
+when the caller set ``JAX_PLATFORMS=cpu`` (the benchmark then sets
+``--xla_force_host_platform_device_count=N`` before jax initializes; with
+fewer accelerator devices than requested it fails).  Every sampled request's
 answer is checked bit-exact against the monolithic ``fn_exits`` serving
 it alone at the same geometry, and the three schedulers must agree
 answer-for-answer: placement moves WHERE stages run, never what they
@@ -41,29 +42,30 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import subprocess
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 
 def ensure_devices(n: int) -> None:
-    """Re-exec in a subprocess with ``n`` forced host devices when this
-    process has fewer — the XLA device count is locked at backend init,
-    so it cannot be raised in-process."""
+    """Make this process see ``n`` devices, or fail.
+
+    One process per chip: nothing here re-execs a child after touching
+    jax.  Forced host devices are used only when the caller already chose
+    the CPU (``JAX_PLATFORMS=cpu``); they are set in-process, before the
+    backend initializes.  On an accelerator, fewer than ``n`` devices is an
+    error, never a silent fall back to the CPU."""
+    if os.environ.get('JAX_PLATFORMS') == 'cpu':
+        flags = [f for f in os.environ.get('XLA_FLAGS', '').split()
+                 if not f.startswith('--xla_force_host_platform_device_count')]
+        flags.append(f'--xla_force_host_platform_device_count={n}')
+        os.environ['XLA_FLAGS'] = ' '.join(flags)
     import jax
-    if len(jax.devices()) >= n or os.environ.get('_REPRO_PIPE_REEXEC'):
-        return
-    env = dict(os.environ, _REPRO_PIPE_REEXEC='1', JAX_PLATFORMS='cpu')
-    flags = [f for f in env.get('XLA_FLAGS', '').split()
-             if not f.startswith('--xla_force_host_platform_device_count')]
-    flags.append(f'--xla_force_host_platform_device_count={n}')
-    env['XLA_FLAGS'] = ' '.join(flags)
-    print(f'{len(jax.devices())} device(s) < {n}: re-running under '
-          f'XLA_FLAGS={flags[-1]}')
-    raise SystemExit(subprocess.call(
-        [sys.executable, os.path.abspath(__file__)] + sys.argv[1:],
-        env=env))
+    devs = jax.devices()
+    if len(devs) < n:
+        raise SystemExit(
+            f'{len(devs)} {devs[0].platform} device(s) < --devices {n}; '
+            f'run with JAX_PLATFORMS=cpu for forced host devices')
 
 
 def makespan(completions) -> float:
@@ -79,7 +81,8 @@ def main():
     ap.add_argument('--requests', type=int, default=256)
     ap.add_argument('--iters', type=int, default=10)
     ap.add_argument('--devices', type=int, default=8,
-                    help='forced host device count (re-execs if needed)')
+                    help='device count; forced host devices under '
+                         'JAX_PLATFORMS=cpu, else a minimum')
     ap.add_argument('--rate', type=float, default=None,
                     help='arrival rate (req/s); default 2x the single-'
                          'device full-depth capacity, so the pipeline '
@@ -108,6 +111,9 @@ def main():
 
     import jax
     import numpy as np
+
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
 
     from serving_load import (check_oracle, measure_stage_costs,
                               poisson_trace, validate_and_write_trace)

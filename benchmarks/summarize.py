@@ -36,14 +36,16 @@ def _cell(tagged):
         return None
     with open(p) as f:
         r = json.load(f)
+    from repro.kernels.tiling import DEVICE_PEAKS, MODELLED_KIND
+    v5e = DEVICE_PEAKS[MODELLED_KIND]     # modelled, not measured
     coll = sum(r['collective_bytes'].values())
     return {'flops': r['flops_per_device'],
-            'compute_s': r['flops_per_device'] / 197e12,
+            'compute_s': r['flops_per_device'] / v5e['bf16_flops'],
             'bytes': r['bytes_per_device'],
             'args_gb': r['memory']['argument_bytes'] / 1e9,
             'mem_s': (2 * r['bytes_per_device']
-                      + r['memory']['argument_bytes']) / 819e9,
-            'coll_s': coll / 50e9}
+                      + r['memory']['argument_bytes']) / v5e['hbm_bytes_per_s'],
+            'coll_s': coll / v5e['ici_link_bytes_per_s']}
 
 
 #: BENCH file -> scheduler-summary keys carrying a ``timeseries`` block

@@ -76,9 +76,9 @@ print(f'trace smoke OK: {len(spans)} spans valid, '
       f'torn-span mutation caught ({len(torn)} violation(s))')
 PY
 
-# pipeline-parallel smoke, on 8 forced host devices (the benchmark
-# re-execs itself under the forced count; JAX_PLATFORMS=cpu keeps the
-# lane deterministic on any box): serves the same tiny trace through the
+# pipeline-parallel smoke, on 8 forced host devices (JAX_PLATFORMS=cpu
+# lets the benchmark force the count in-process; on an accelerator it
+# fails with fewer devices instead): serves the same tiny trace through the
 # single-device scheduler and the placed pipeline, asserting every
 # request bit-exact vs the monolithic oracle and the recorded spans
 # (incl. transfer.carry) strictly valid.  Then the placement-consistency

@@ -51,15 +51,9 @@ def pallas_call_vmem_bytes(eqn) -> int:
     still gets caught at export.
     """
     gm = eqn.params['grid_mapping']
-    total = 0
-    for bm in gm.block_mappings:
-        n = 1
-        for d in bm.block_shape:
-            try:
-                n *= int(d)
-            except TypeError:      # squeezed/None entries carry no extent
-                continue
-        total += n * bm.array_shape_dtype.dtype.itemsize
+    # block_aval: the block's shape (squeezed dims dropped) at the operand
+    # dtype — what one grid step holds in VMEM
+    total = sum(_aval_bytes(bm.block_aval) for bm in gm.block_mappings)
     inner = eqn.params['jaxpr']
     n_io = gm.num_inputs + gm.num_outputs
     for v in inner.invars[n_io:]:
@@ -68,7 +62,10 @@ def pallas_call_vmem_bytes(eqn) -> int:
 
 
 def pallas_call_name(eqn) -> str:
-    """The kernel's debug name ('quant_matmul', 'lowrank_conv', ...)."""
-    info = eqn.params.get('name_and_src_info')
-    name = getattr(info, 'name', None) or str(info or 'pallas_call')
-    return name.split()[0]
+    """The kernel's debug name ('_qmm_kernel', '_lr_kernel', ...): the
+    explicit ``name=`` when one was given, else the kernel function's."""
+    if eqn.params.get('name'):
+        return str(eqn.params['name']).split()[0]
+    info = getattr(eqn.params['jaxpr'], 'debug_info', None)
+    src = getattr(info, 'func_src_info', None) or 'pallas_call'
+    return src.split()[0]

@@ -48,8 +48,9 @@ class Trainer:
     seed: int = 0
 
     def fit(self, family, cfg, params, *, loss_fn=None, lr=None, steps=None,
-            train_keys=None, seed=None):
-        """SGD loop; train_keys restricts training to those top-level keys."""
+            train_keys=None, seed=None, losses=None):
+        """SGD loop; train_keys restricts training to those top-level keys.
+        ``losses`` (a list) receives every step's loss, in order."""
         from repro.optim import adamw, apply_updates, clip_by_global_norm
         loss_fn = loss_fn or family.loss
         lr = self.lr if lr is None else lr
@@ -75,6 +76,8 @@ class Trainer:
         for i in range(steps):
             batch = family.train_batch(jax.random.fold_in(key, i), self.batch)
             params, opt_state, last = step(params, opt_state, batch)
+            if losses is not None:
+                losses.append(float(last))
         return params, float(last) if last is not None else None
 
     def evaluate(self, family, cfg, params):

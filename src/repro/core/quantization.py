@@ -53,7 +53,10 @@ def quantize_weight(w: jax.Array, bits: int, *, axis=-1):
         return q.astype(jnp.int8), scale
     qmax = 2.0 ** (bits - 1) - 1.0
     amax = jnp.max(jnp.abs(w), axis=red, keepdims=red is not None)
-    scale = jnp.maximum(amax, 1e-8) / qmax
+    # times the reciprocal, not divided by qmax: XLA rewrites a division by
+    # a constant into this multiply inside jit, so writing it out keeps the
+    # eager export and a jitted forward on the same scale bits
+    scale = jnp.maximum(amax, 1e-8) * (1.0 / qmax)
     q = jnp.clip(jnp.round(w / scale), -qmax - 1, qmax)
     return q.astype(jnp.int8 if bits <= 8 else jnp.int32), scale
 
@@ -97,6 +100,30 @@ def _kfq_bwd(bits, _res, g):
 _kernel_fake_quant_ste.defvjp(_kfq_fwd, _kfq_bwd)
 
 
+def fake_quant_weight_codes(w: jax.Array, bits: int):
+    """Per-out-channel (last axis) weight fake-quant split as (codes,
+    scale): ``codes * scale`` is :func:`fake_quant_weight`'s value.  The
+    codes are exact integers carrying the STE gradient; the scale is
+    constant to autodiff, as in :func:`fake_quant_weight`."""
+    q, scale = quantize_weight(w, bits, axis=-1)
+    scale = jax.lax.stop_gradient(scale.astype(w.dtype))
+    codes = _ste(q.astype(w.dtype), w / scale)
+    # opaque to XLA's algebraic simplifier, like the export's precomputed
+    # scale: otherwise it reassociates (amax_x * c) * (amax_w * c) in the
+    # epilogue and rounds the product differently from serving
+    return codes, jax.lax.optimization_barrier(scale.reshape(-1))
+
+
+def fake_quant_act_codes(x: jax.Array, bits: int):
+    """Activation fake-quant split as (codes, scale), the per-tensor
+    dynamic grid of :func:`fake_quant_act`."""
+    qmax = 2.0 ** (bits - 1) - 1.0
+    amax = jax.lax.stop_gradient(jnp.maximum(jnp.max(jnp.abs(x)), 1e-8))
+    s = amax * (1.0 / qmax)
+    v = x / s
+    return _ste(jnp.clip(jnp.round(v), -qmax - 1, qmax), v), s
+
+
 def fake_quant_act(x: jax.Array, bits: int, *, amax: float | None = None) -> jax.Array:
     """Activation fake-quant: symmetric uniform with running-free abs-max clip.
 
@@ -108,7 +135,7 @@ def fake_quant_act(x: jax.Array, bits: int, *, amax: float | None = None) -> jax
         return x
     qmax = 2.0 ** (bits - 1) - 1.0
     s = jnp.max(jnp.abs(x)) if amax is None else jnp.asarray(amax, x.dtype)
-    s = jax.lax.stop_gradient(jnp.maximum(s, 1e-8)) / qmax
+    s = jax.lax.stop_gradient(jnp.maximum(s, 1e-8)) * (1.0 / qmax)
     xq = jnp.clip(jnp.round(x / s), -qmax - 1, qmax) * s
     return _ste(xq.astype(x.dtype), x)
 

@@ -20,10 +20,12 @@ by expanding the input channel axis to the output channels
 (``x_e[..., o] = x[..., o // mult]``, a pure int8 memory-layout op);
 per-group depth > 1 has no per-channel lowering and stays on the declared
 fallback (no such layer exists in this repo's families).  Grid is
-``(B, COUT/bc)``: each step holds one padded spatial plane
-``(HP, WP, bc)`` in VMEM, unrolls the KH*KW taps as strided-slice
-multiply-accumulates into an int32 register tile, and runs the epilogue
-once — one kernel launch per layer, zero accumulator traffic to HBM.
+``(B, COUT/bc)``: each step holds one padded spatial plane in VMEM —
+de-interleaved into its stride phases outside the kernel
+(:func:`_phase_planes`), so every tap is a unit-stride slice — unrolls the
+KH*KW taps as multiply-accumulates into an int32 register tile, and runs
+the epilogue once — one kernel launch per layer, zero accumulator traffic
+to HBM.
 
 Bit-exactness contract (tested): the int32 accumulation is exact, and the
 fp32 epilogue op order (``acc * (sx * sw) + b``, ReLU, requantize) matches
@@ -38,6 +40,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.tiling import LANE, VMEM_BUDGET, pad_to
 
@@ -63,23 +66,43 @@ def _same_pads(h: int, w: int, kh: int, kw: int, stride: int):
             (pad_w // 2, pad_w - pad_w // 2), oh, ow)
 
 
+def _phase_planes(x, stride: int):
+    """De-interleave a padded (B, HP, WP, C) plane into its stride phases:
+    (B, stride**2, HP/stride, WP/stride, C), phase ``a * stride + b``
+    holding rows ``a::stride`` and columns ``b::stride`` (HP/WP zero-padded
+    up to a stride multiple first — value-exact, symmetric int8 zeros).
+
+    Tap (i, j) of a stride-s conv then reads phase ``(i % s, j % s)`` at
+    the unit-stride offset ``(i // s, j // s)``, so the kernel never takes
+    a strided slice in VMEM (Mosaic refuses strides other than 1 there).
+    A pure int8 memory-layout op; stride 1 is a free reshape."""
+    B, hp, wp, C = x.shape
+    if stride == 1:
+        return x[:, None]
+    hq, wq = -(-hp // stride), -(-wp // stride)
+    x = jnp.pad(x, ((0, 0), (0, hq * stride - hp), (0, wq * stride - wp),
+                    (0, 0)))
+    x = x.reshape(B, hq, stride, wq, stride, C).transpose(0, 2, 4, 1, 3, 5)
+    return x.reshape(B, stride * stride, hq, wq, C)
+
+
 def _dw_kernel(x_ref, w_ref, sx_ref, sw_ref, b_ref, o_ref, *, kh, kw,
                stride, oh, ow, relu, out_scale, out_qmax):
-    x = x_ref[0]                                     # (HP, WP, bc) int8
+    planes = [x_ref[0, p] for p in range(stride * stride)]  # (HQ, WQ, bc)
+    w = w_ref[...].astype(jnp.int32)                 # (KH*KW, bc)
     acc = jnp.zeros(o_ref.shape[1:], jnp.int32)      # (OH, OW, bc) registers
     for i in range(kh):                              # unrolled taps: the
         for j in range(kw):                          # whole window sum is
-            win = jax.lax.slice(                     # per-channel VPU FMAs
-                x, (i, j, 0),
-                (i + (oh - 1) * stride + 1, j + (ow - 1) * stride + 1,
-                 x.shape[-1]),
-                (stride, stride, 1))
-            acc += win.astype(jnp.int32) * w_ref[i * kw + j].astype(
-                jnp.int32)[None, None, :]
+            x = planes[(i % stride) * stride + j % stride]  # per-channel
+            r0, c0 = i // stride, j // stride        # VPU FMAs, unit stride
+            win = jax.lax.slice(x, (r0, c0, 0), (r0 + oh, c0 + ow,
+                                                 x.shape[-1]))
+            t = i * kw + j
+            acc += win.astype(jnp.int32) * w[t:t + 1][None]
     # shared epilogue, same fp32 op order as quant_matmul's: dequant on the
     # (sx * sw) product, bias, ReLU, optional static requantize to int8
-    y = acc.astype(jnp.float32) * (sx_ref[0] * sw_ref[...])[None, None, :]
-    y = y + b_ref[...][None, None, :]
+    y = acc.astype(jnp.float32) * (sx_ref[0, 0] * sw_ref[...])[None]
+    y = y + b_ref[...][None]
     if relu:
         y = jnp.maximum(y, 0.0)
     if out_scale is not None:
@@ -98,10 +121,11 @@ def depthwise_conv(x_q, w_q, sx, sw, bias=None, *, stride=1, relu=False,
     x_q: int8 (B,H,W,CIN); w_q: int8 (KH,KW,1,COUT) with COUT an integer
     multiple of CIN (the channel multiplier; COUT == CIN is plain
     depthwise); sx: scalar fp32 per-tensor activation scale (static float
-    or traced scalar — it rides as a (1,) operand, not a trace constant);
-    sw: (COUT,) fp32 static per-channel weight scales; bias: (COUT,) fp32
-    or None.  Returns (B,OH,OW,COUT) ``out_dtype``, or int8 when the
-    ``out_scale`` requantize epilogue is selected (cf. quant_matmul).
+    or traced scalar — it rides in SMEM as a (1, 1) operand, not a trace
+    constant); sw: (COUT,) fp32 static per-channel weight scales; bias:
+    (COUT,) fp32 or None.  Returns (B,OH,OW,COUT) ``out_dtype``, or int8
+    when the ``out_scale`` requantize epilogue is selected (cf.
+    quant_matmul).
     """
     B, H, W, C = x_q.shape
     kh, kw, cg, n = w_q.shape
@@ -116,14 +140,17 @@ def depthwise_conv(x_q, w_q, sx, sw, bias=None, *, stride=1, relu=False,
     bc = min(bc, np_)
     if np_ != n:
         x_q = jnp.pad(x_q, ((0, 0), (0, 0), (0, 0), (0, np_ - n)))
-    hp, wp = x_q.shape[1], x_q.shape[2]
-    assert (hp * wp + 4 * oh * ow + 4 * oh * ow) * bc <= VMEM_BUDGET, \
-        (hp, wp, bc)
+    x_q = _phase_planes(x_q, stride)
+    _, n_ph, hq, wq, _ = x_q.shape
+    assert (n_ph * hq * wq + 4 * oh * ow + 4 * oh * ow) * bc <= \
+        VMEM_BUDGET, (n_ph, hq, wq, bc)
     w2 = jnp.pad(w_q.reshape(kh * kw, n), ((0, 0), (0, np_ - n)))
-    sw = jnp.pad(sw.astype(jnp.float32), (0, np_ - n))
-    b = (jnp.zeros((n,), jnp.float32) if bias is None
-         else bias.astype(jnp.float32))
-    b = jnp.pad(b, (0, np_ - n))
+
+    def row(a):       # (1, np_) rows: no 1-D f32 blocks under Mosaic
+        return jnp.pad(a.astype(jnp.float32).reshape(1, n),
+                       ((0, 0), (0, np_ - n)))
+    sw = row(sw)
+    b = row(jnp.zeros((n,), jnp.float32) if bias is None else bias)
     if out_scale is not None:
         out_scale, out_dtype = float(out_scale), jnp.int8
     out = pl.pallas_call(
@@ -132,14 +159,15 @@ def depthwise_conv(x_q, w_q, sx, sw, bias=None, *, stride=1, relu=False,
                           out_qmax=float(out_qmax)),
         grid=(B, np_ // bc),
         in_specs=[
-            pl.BlockSpec((1, hp, wp, bc), lambda b, c: (b, 0, 0, c)),
+            pl.BlockSpec((1, n_ph, hq, wq, bc),
+                         lambda b, c: (b, 0, 0, 0, c)),
             pl.BlockSpec((kh * kw, bc), lambda b, c: (0, c)),
-            pl.BlockSpec((1,), lambda b, c: (0,)),
-            pl.BlockSpec((bc,), lambda b, c: (c,)),
-            pl.BlockSpec((bc,), lambda b, c: (c,)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, bc), lambda b, c: (0, c)),
+            pl.BlockSpec((1, bc), lambda b, c: (0, c)),
         ],
         out_specs=pl.BlockSpec((1, oh, ow, bc), lambda b, c: (b, 0, 0, c)),
         out_shape=jax.ShapeDtypeStruct((B, oh, ow, np_), out_dtype),
         interpret=interpret,
-    )(x_q, w2, jnp.reshape(jnp.asarray(sx, jnp.float32), (1,)), sw, b)
+    )(x_q, w2, jnp.reshape(jnp.asarray(sx, jnp.float32), (1, 1)), sw, b)
     return out[..., :n]

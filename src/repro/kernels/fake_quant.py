@@ -6,7 +6,7 @@ strategies:
 
 * :func:`fake_quant` — two VMEM-tiled kernels (reduction kernel accumulates
   per-column amax across K tiles; quantize kernel is a single elementwise
-  sweep with the (bn,)-scales block resident in VMEM).  W streams through
+  sweep with the (1, bn) scale row resident in VMEM).  W streams through
   HBM twice (amax read + quantize read/write).
 * :func:`fake_quant_fused` — single-pass variant: each grid step holds a
   full (K, bn) column stripe in VMEM, computes the per-column amax and
@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.tiling import fit_or_pad
+from repro.kernels.tiling import LANE, fit_or_pad
 
 
 def _amax_kernel(w_ref, o_ref, *, n_k):
@@ -36,14 +36,15 @@ def _amax_kernel(w_ref, o_ref, *, n_k):
         o_ref[...] = jnp.zeros_like(o_ref)
 
     o_ref[...] = jnp.maximum(o_ref[...],
-                             jnp.max(jnp.abs(w_ref[...]), axis=0))
+                             jnp.max(jnp.abs(w_ref[...]), axis=0,
+                                     keepdims=True))
 
 
 def _quant_kernel(w_ref, amax_ref, o_ref, *, qmax):
-    scale = jnp.maximum(amax_ref[...], 1e-8) / qmax
-    w = w_ref[...] / scale[None, :]
+    scale = jnp.maximum(amax_ref[...], 1e-8) / qmax      # (1, bn) row
+    w = w_ref[...] / scale
     o_ref[...] = (jnp.clip(jnp.round(w), -qmax - 1, qmax)
-                  * scale[None, :]).astype(o_ref.dtype)
+                  * scale).astype(o_ref.dtype)
 
 
 def _fused_kernel(w_ref, o_ref, *, qmax):
@@ -64,22 +65,22 @@ def _pad2(w, K, N, Kp, Np):
 def fake_quant(w, *, bits=8, bk=512, bn=256, interpret=False):
     """Per-output-channel (last-dim) symmetric fake quant of w (K, N)."""
     K, N = w.shape
-    (bk, Kp), (bn, Np) = fit_or_pad(bk, K), fit_or_pad(bn, N)
+    (bk, Kp), (bn, Np) = fit_or_pad(bk, K), fit_or_pad(bn, N, align=LANE)
     w = _pad2(w, K, N, Kp, Np)
     qmax = 2.0 ** (bits - 1) - 1.0
     amax = pl.pallas_call(
         functools.partial(_amax_kernel, n_k=Kp // bk),
         grid=(Np // bn, Kp // bk),
         in_specs=[pl.BlockSpec((bk, bn), lambda j, k: (k, j))],
-        out_specs=pl.BlockSpec((bn,), lambda j, k: (j,)),
-        out_shape=jax.ShapeDtypeStruct((Np,), jnp.float32),
+        out_specs=pl.BlockSpec((1, bn), lambda j, k: (0, j)),
+        out_shape=jax.ShapeDtypeStruct((1, Np), jnp.float32),
         interpret=interpret,
     )(w.astype(jnp.float32))
     out = pl.pallas_call(
         functools.partial(_quant_kernel, qmax=qmax),
         grid=(Kp // bk, Np // bn),
         in_specs=[pl.BlockSpec((bk, bn), lambda i, j: (i, j)),
-                  pl.BlockSpec((bn,), lambda i, j: (j,))],
+                  pl.BlockSpec((1, bn), lambda i, j: (0, j))],
         out_specs=pl.BlockSpec((bk, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Kp, Np), w.dtype),
         interpret=interpret,
@@ -95,7 +96,7 @@ def fake_quant_fused(w, *, bits=8, bn=256, interpret=False):
     reduction and the rounding sweep fuse into one kernel.
     """
     K, N = w.shape
-    bn, Np = fit_or_pad(bn, N)
+    bn, Np = fit_or_pad(bn, N, align=LANE)
     w = _pad2(w, K, N, K, Np)
     qmax = 2.0 ** (bits - 1) - 1.0
     out = pl.pallas_call(

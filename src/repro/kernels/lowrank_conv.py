@@ -46,14 +46,11 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.quant_conv import im2col_nhwc
-from repro.kernels.tiling import VMEM_BUDGET, fit_or_pad, pad_to
+from repro.kernels.tiling import (LANE, VMEM_BUDGET, device_peaks,
+                                  fit_or_pad, pad_to)
 
-# Serving-cost constants for lowering_costs (TPU v5e, cf. benchmarks/
-# roofline.py): int8 MXU peak 394 TOP/s = 197e6 MACs/us, HBM 819 GB/s =
-# 819e3 bytes/us, and ~2us of per-launch dispatch overhead — the term the
-# two-launch chained path pays twice.
-MACS_PER_US = 197e6
-BYTES_PER_US = 819e3
+# Per-launch dispatch overhead lowering_costs charges (the term the
+# two-launch chained path pays twice) — an assumption, not a measurement.
 LAUNCH_US = 2.0
 
 
@@ -86,12 +83,17 @@ def lowering_costs(m: int, k1: int, r: int, n: int, *, bm: int = 128,
     each output block exactly once.  Per-launch time is the roofline max of
     its compute and traffic terms; the chained total is the sum of its two
     launches.  Used by core/export.py ``select_kernels='model'`` (the
-    default) — 'measure' mode times the two lowerings instead.
+    default) — 'measure' mode times the two lowerings instead.  Peaks come
+    from :func:`tiling.device_peaks`: the chip's own row on a TPU, the v5e
+    row off the chip (``peaks_modelled=True`` in the result).
     """
-    (bm, mp), (bk, k1p) = fit_or_pad(bm, m), fit_or_pad(bk, k1)
-    (bn, np_) = fit_or_pad(bn, n)
+    (bm, mp), (bk, k1p) = fit_or_pad(bm, m), fit_or_pad(bk, k1, align=LANE)
+    (bn, np_) = fit_or_pad(bn, n, align=LANE)
     rp = pad_to(r)
-    n_m, n_k, n_n = mp // bm, k1p // bk, np_ // bn
+    n_m, n_k = mp // bm, k1p // bk
+    peaks = device_peaks()
+    macs_per_us = peaks['int8_ops'] / 2 / 1e6      # 2 ops per MAC
+    bytes_per_us = peaks['hbm_bytes_per_s'] / 1e6
     macs_u = mp * k1p * rp          # padded-domain MACs, what the MXU runs
     macs_v = mp * rp * np_
     fused_bytes = (mp * k1p              # patches: once per (i, k), N inner
@@ -100,15 +102,16 @@ def lowering_costs(m: int, k1: int, r: int, n: int, *, bm: int = 128,
                    + n_k * mp * np_)    # output flushed once per K revisit
     chained_bytes_u = mp * k1p + n_m * k1p * rp + mp * rp
     chained_bytes_v = mp * rp + n_m * rp * np_ + mp * np_
-    fused_us = LAUNCH_US + max((macs_u + macs_v) / MACS_PER_US,
-                               fused_bytes / BYTES_PER_US)
+    fused_us = LAUNCH_US + max((macs_u + macs_v) / macs_per_us,
+                               fused_bytes / bytes_per_us)
     chained_us = (2 * LAUNCH_US
-                  + max(macs_u / MACS_PER_US, chained_bytes_u / BYTES_PER_US)
-                  + max(macs_v / MACS_PER_US, chained_bytes_v / BYTES_PER_US))
+                  + max(macs_u / macs_per_us, chained_bytes_u / bytes_per_us)
+                  + max(macs_v / macs_per_us, chained_bytes_v / bytes_per_us))
     return {'fused_us': fused_us, 'chained_us': chained_us,
             'fused_bytes': fused_bytes,
             'chained_bytes': chained_bytes_u + chained_bytes_v,
-            'macs': macs_u + macs_v}
+            'macs': macs_u + macs_v, 'peaks_kind': peaks['kind'],
+            'peaks_modelled': peaks['modelled']}
 
 
 def _lr_kernel(x_ref, u_ref, su_ref, bu_ref, v_ref, sv_ref, bv_ref, o_ref,
@@ -133,8 +136,8 @@ def _lr_kernel(x_ref, u_ref, su_ref, bu_ref, v_ref, sv_ref, bv_ref, o_ref,
         # same fp32 op order as quant_matmul's epilogue, so the fused and
         # chained paths agree bit-for-bit.  h persists in scratch across
         # the whole N sweep.
-        h = acc_ref[...].astype(jnp.float32) * (sx * su_ref[...][None, :])
-        h = h + bu_ref[...][None, :]
+        h = acc_ref[...].astype(jnp.float32) * (sx * su_ref[...])
+        h = h + bu_ref[...]
         hq_ref[...] = jnp.clip(jnp.round(h / h_scale), -h_qmax - 1.0,
                                h_qmax).astype(jnp.int8)
 
@@ -144,8 +147,8 @@ def _lr_kernel(x_ref, u_ref, su_ref, bu_ref, v_ref, sv_ref, bv_ref, o_ref,
         acc2 = jax.lax.dot_general(
             hq_ref[...], v_ref[...], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.int32)
-        y = acc2.astype(jnp.float32) * (h_scale * sv_ref[...][None, :])
-        y = y + bv_ref[...][None, :]
+        y = acc2.astype(jnp.float32) * (h_scale * sv_ref[...])
+        y = y + bv_ref[...]
         if relu:
             y = jnp.maximum(y, 0.0)
         if out_scale is not None:
@@ -180,8 +183,8 @@ def lowrank_conv(x_q, u_q, v_q, su, sv, bu, bv, *, sx, h_scale, stride=1,
     m = B * oh * ow
     k1 = kh * kw * C
 
-    (bm, mp), (bk, k1p) = fit_or_pad(bm, m), fit_or_pad(bk, k1)
-    (bn, np_) = fit_or_pad(bn, n)
+    (bm, mp), (bk, k1p) = fit_or_pad(bm, m), fit_or_pad(bk, k1, align=LANE)
+    (bn, np_) = fit_or_pad(bn, n, align=LANE)
     rp = pad_to(r)
     assert rp <= 128, (r, 'rank exceeds the fused envelope; chain instead')
     # resident per grid step: x/u/v blocks + int32 acc + int8 h + out tile
@@ -191,10 +194,12 @@ def lowrank_conv(x_q, u_q, v_q, su, sv, bu, bv, *, sx, h_scale, stride=1,
         patches = jnp.pad(patches, ((0, mp - m), (0, k1p - k1)))
     u2 = jnp.pad(u_q.reshape(k1, r), ((0, k1p - k1), (0, rp - r)))
     v2 = jnp.pad(v_q, ((0, rp - r), (0, np_ - n)))
-    su = jnp.pad(su.astype(jnp.float32), (0, rp - r))
-    bu = jnp.pad(bu.astype(jnp.float32), (0, rp - r))
-    sv = jnp.pad(sv.astype(jnp.float32), (0, np_ - n))
-    bv = jnp.pad(bv.astype(jnp.float32), (0, np_ - n))
+    # scales and biases ride as (1, width) rows: Mosaic cannot lay out a
+    # 1-D f32 block that is not the whole array
+    def row(a, width):
+        a = a.astype(jnp.float32).reshape(1, -1)
+        return jnp.pad(a, ((0, 0), (0, width - a.shape[1])))
+    su, bu, sv, bv = row(su, rp), row(bu, rp), row(sv, np_), row(bv, np_)
 
     n_k = k1p // bk
     grid = (mp // bm, n_k, np_ // bn)
@@ -209,11 +214,11 @@ def lowrank_conv(x_q, u_q, v_q, su, sv, bu, bv, *, sx, h_scale, stride=1,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, k, j: (i, k)),
             pl.BlockSpec((bk, rp), lambda i, k, j: (k, 0)),
-            pl.BlockSpec((rp,), lambda i, k, j: (0,)),
-            pl.BlockSpec((rp,), lambda i, k, j: (0,)),
+            pl.BlockSpec((1, rp), lambda i, k, j: (0, 0)),
+            pl.BlockSpec((1, rp), lambda i, k, j: (0, 0)),
             pl.BlockSpec((rp, bn), lambda i, k, j: (0, j)),
-            pl.BlockSpec((bn,), lambda i, k, j: (j,)),
-            pl.BlockSpec((bn,), lambda i, k, j: (j,)),
+            pl.BlockSpec((1, bn), lambda i, k, j: (0, j)),
+            pl.BlockSpec((1, bn), lambda i, k, j: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, k, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),

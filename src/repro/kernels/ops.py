@@ -115,10 +115,10 @@ def quantize_act(x, *, a_bits: int = 8, per_row: bool = False):
     """
     qmax = _act_qmax(a_bits)
     if per_row:
-        s = jnp.maximum(jnp.max(jnp.abs(x), axis=1), 1e-8) / qmax
+        s = jnp.maximum(jnp.max(jnp.abs(x), axis=1), 1e-8) * (1.0 / qmax)
         xq = jnp.clip(jnp.round(x / s[:, None]), -qmax - 1, qmax)
     else:
-        s = jnp.maximum(jnp.max(jnp.abs(x)), 1e-8) / qmax
+        s = jnp.maximum(jnp.max(jnp.abs(x)), 1e-8) * (1.0 / qmax)
         xq = jnp.clip(jnp.round(x / s), -qmax - 1, qmax)
     return xq.astype(jnp.int8), s.astype(jnp.float32)
 
@@ -130,8 +130,6 @@ def quant_dense(x, w_q, sw, *, a_bits=8, per_row=True, use_pallas=True, **kw):
     weight scales sw (N,) are static.  Returns fp32 (M, N).
     """
     xq, sx = quantize_act(x, a_bits=a_bits, per_row=per_row)
-    if not per_row:
-        sx = jnp.full((x.shape[0],), sx, jnp.float32)
     return quant_matmul(xq, w_q, sx, sw.reshape(-1), use_pallas=use_pallas,
                         **kw)
 
@@ -222,9 +220,7 @@ def quant_dense_static(x_q, w_q, sw, bias=None, *, sx, relu=False,
     :func:`quant_conv_static`).  x_q int8 (M,K); returns fp32 (M,N), or
     int8 when ``out_scale`` is set."""
     if not use_pallas:
-        y = ref.quant_matmul_ref(x_q, w_q,
-                                 jnp.full((x_q.shape[0],), sx, jnp.float32),
-                                 sw.reshape(-1))
+        y = ref.quant_matmul_ref(x_q, w_q, sx, sw.reshape(-1))
         if bias is not None:
             y = y + bias.astype(jnp.float32)
         if relu:
@@ -232,9 +228,9 @@ def quant_dense_static(x_q, w_q, sw, bias=None, *, sx, relu=False,
         if out_scale is not None:
             return ref.requantize(y, out_scale, out_qmax)
         return y
-    return _pallas_qmm(x_q, w_q, jnp.full((x_q.shape[0],), sx, jnp.float32),
-                       sw.reshape(-1), bias, relu=relu, out_scale=out_scale,
-                       out_qmax=out_qmax, interpret=_interpret(), **kw)
+    return _pallas_qmm(x_q, w_q, sx, sw.reshape(-1), bias, relu=relu,
+                       out_scale=out_scale, out_qmax=out_qmax,
+                       interpret=_interpret(), **kw)
 
 
 def lowrank_conv_nhwc(x_q, u_q, v_q, su, sv, bu, bv, *, sx, h_scale,

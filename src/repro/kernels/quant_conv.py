@@ -98,9 +98,8 @@ def quant_conv(x_q, w_q, sx, sw, bias=None, *, stride=1, relu=False,
     kh, kw, c2, n = w_q.shape
     assert C == c2, (C, c2)
     patches, (oh, ow) = im2col_nhwc(x_q, kh, kw, stride)
-    m = B * oh * ow
     out = quant_matmul(patches, w_q.reshape(kh * kw * C, n),
-                       jnp.full((m,), sx, jnp.float32),
+                       jnp.asarray(sx, jnp.float32),
                        sw.astype(jnp.float32), bias,
                        bm=bm, bn=bn, bk=bk, out_dtype=out_dtype, relu=relu,
                        interpret=interpret, out_scale=out_scale,

@@ -19,10 +19,14 @@ def requantize(y, out_scale, qmax=127.0):
 
 
 def quant_matmul_ref(x_q, w_q, sx, sw, out_dtype=jnp.float32):
-    """int8 x (M,K) @ int8 w (K,N), per-row sx (M,), per-col sw (N,)."""
+    """int8 x (M,K) @ int8 w (K,N); sx per-tensor scalar or per-row (M,),
+    per-col sw (N,).  Same epilogue op order as the kernel:
+    ``acc * (sx * sw)``."""
     acc = jnp.einsum('mk,kn->mn', x_q.astype(jnp.int32), w_q.astype(jnp.int32),
                      preferred_element_type=jnp.int32)
-    return (acc.astype(jnp.float32) * sx[:, None] * sw[None, :]).astype(out_dtype)
+    sx = jnp.asarray(sx, jnp.float32)
+    scale = (sx[:, None] if sx.ndim else sx) * sw.astype(jnp.float32)[None, :]
+    return (acc.astype(jnp.float32) * scale).astype(out_dtype)
 
 
 def fake_quant_ref(w, bits: int):
@@ -33,24 +37,46 @@ def fake_quant_ref(w, bits: int):
     return jnp.clip(jnp.round(w / scale), -qmax - 1, qmax) * scale
 
 
+def _int_conv(x_q, w_q, stride, groups):
+    """Exact integer SAME conv of int8 codes, returned as fp32.
+
+    fp32 holds every partial sum exactly while ``K * 128 * 128 < 2**24``
+    (K = KH*KW*per-group CIN), so small-K convs run XLA's fast fp32 conv on
+    the raw codes; larger K accumulates in int32 (exact at any K the
+    kernels serve).  Either way the result equals the kernels' int32
+    accumulator."""
+    kh, kw, cg, _ = w_q.shape
+    dt = (jnp.float32 if kh * kw * cg * 128 * 128 < 2 ** 24
+          or x_q.dtype != jnp.int8 else jnp.int8)
+    acc = jax.lax.conv_general_dilated(
+        x_q.astype(dt), w_q.astype(dt), (stride, stride), 'SAME',
+        feature_group_count=groups,
+        dimension_numbers=('NHWC', 'HWIO', 'NHWC'),
+        preferred_element_type=(jnp.float32 if dt == jnp.float32
+                                else jnp.int32))
+    return acc.astype(jnp.float32)
+
+
 def quant_conv_ref(x_q, w_q, sx, sw, bias=None, *, stride=1, relu=False,
                    groups=1, out_dtype=jnp.float32, out_scale=None,
                    out_qmax=127.0):
-    """lax.conv oracle for kernels/quant_conv.quant_conv.
+    """lax.conv oracle for kernels/quant_conv.quant_conv (and, with
+    ``groups == CIN``, kernels/depthwise_conv.depthwise_conv).
 
-    Dequantizes both operands and runs the SAME-padded fp32 conv — the conv
-    is bilinear, so this equals the int8-accumulate + epilogue-rescale path
-    up to fp32 rounding.  x_q int8 NHWC, w_q int8 HWIO, sx scalar, sw
-    (COUT,).  ``out_scale`` mirrors the kernels' requantize epilogue
-    (int8 output on a static grid).
+    Accumulates on the *raw integer codes* (:func:`_int_conv`, exact) and
+    then applies the kernels' fp32 epilogue in their op order —
+    ``acc * (sx * sw)``, bias, ReLU, optional requantize on the static
+    ``out_scale`` grid.  Dequantizing the operands before the conv is the
+    same math on paper but rounds differently, and a dynamic per-tensor
+    requantize downstream turns that last-ulp difference into flipped int8
+    codes; this order keeps the jnp serving path on the kernels' values.
+    x_q int8 NHWC, w_q int8 HWIO, sx scalar, sw (COUT,).
     """
-    x = x_q.astype(jnp.float32) * jnp.asarray(sx, jnp.float32)
-    w = w_q.astype(jnp.float32) * sw.astype(jnp.float32)[None, None, None, :]
-    y = jax.lax.conv_general_dilated(
-        x, w, (stride, stride), 'SAME', feature_group_count=groups,
-        dimension_numbers=('NHWC', 'HWIO', 'NHWC'))
+    acc = _int_conv(x_q, w_q, stride, groups)
+    scale = jnp.asarray(sx, jnp.float32) * sw.astype(jnp.float32)
+    y = acc * scale[None, None, None, :]
     if bias is not None:
-        y = y + bias.astype(jnp.float32)
+        y = y + bias.astype(jnp.float32)[None, None, None, :]
     if relu:
         y = jnp.maximum(y, 0.0)
     if out_scale is not None:
@@ -62,15 +88,9 @@ def quant_conv_ref(x_q, w_q, sx, sw, bias=None, *, stride=1, relu=False,
                                              'out_scale', 'out_qmax'))
 def depthwise_conv_ref(x_q, w_q, sx, sw, bias=None, *, stride=1, relu=False,
                        out_dtype=jnp.float32, out_scale=None, out_qmax=127.0):
-    """lax.conv oracle for kernels/depthwise_conv.depthwise_conv, BIT-exact.
-
-    Unlike :func:`quant_conv_ref` (which dequantizes before the conv), this
-    accumulates on the *raw integer codes*: fp32 holds every depthwise
-    partial sum exactly (<= KH*KW*127^2 << 2^24), so the lax.conv
-    accumulation equals the kernel's int32 accumulation bit-for-bit, and
-    the epilogue applies the identical fp32 op order — ``acc * (sx * sw)``,
-    bias, ReLU, requantize.  x_q int8 (B,H,W,CIN); w_q int8 (KH,KW,1,COUT)
-    with COUT a multiple of CIN (feature_group_count = CIN).
+    """Bit-exact oracle for kernels/depthwise_conv.depthwise_conv:
+    :func:`quant_conv_ref` with ``feature_group_count = CIN``.  x_q int8
+    (B,H,W,CIN); w_q int8 (KH,KW,1,COUT) with COUT a multiple of CIN.
 
     Jitted on purpose: op-by-op dispatch compiles ``acc * scale + bias``
     without the fused multiply-add contraction XLA applies inside a traced
@@ -79,20 +99,9 @@ def depthwise_conv_ref(x_q, w_q, sx, sw, bias=None, *, stride=1, relu=False,
     identical and the fp32 outputs agree bit-for-bit (the int8
     ``out_scale`` outputs agree either way — rounding absorbs the ulp).
     """
-    groups = x_q.shape[-1]
-    acc = jax.lax.conv_general_dilated(
-        x_q.astype(jnp.float32), w_q.astype(jnp.float32), (stride, stride),
-        'SAME', feature_group_count=groups,
-        dimension_numbers=('NHWC', 'HWIO', 'NHWC'))
-    scale = jnp.asarray(sx, jnp.float32) * sw.astype(jnp.float32)
-    y = acc * scale[None, None, None, :]
-    if bias is not None:
-        y = y + bias.astype(jnp.float32)[None, None, None, :]
-    if relu:
-        y = jnp.maximum(y, 0.0)
-    if out_scale is not None:
-        return requantize(y, out_scale, out_qmax)
-    return y.astype(out_dtype)
+    return quant_conv_ref(x_q, w_q, sx, sw, bias, stride=stride, relu=relu,
+                          groups=x_q.shape[-1], out_dtype=out_dtype,
+                          out_scale=out_scale, out_qmax=out_qmax)
 
 
 def lowrank_conv_ref(x_q, u_q, v_q, su, sv, bu, bv, *, sx, h_scale, stride=1,

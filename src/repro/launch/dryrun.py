@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 Proves the distribution config is coherent without hardware: sharding
@@ -15,6 +12,7 @@ Usage:
 """
 import argparse
 import json
+import os
 import re
 import time
 
@@ -179,6 +177,10 @@ def run_cell(arch: str, shape: str, mesh_name: str, *, fsdp=True,
 
 
 def main():
+    # 512 virtual host devices for the multi-pod meshes; the count locks
+    # when jax initializes its backend, so it is set here, before any jax
+    # import, and never at module import
+    os.environ['XLA_FLAGS'] = '--xla_force_host_platform_device_count=512'
     ap = argparse.ArgumentParser()
     ap.add_argument('--arch')
     ap.add_argument('--shape')

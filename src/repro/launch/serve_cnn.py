@@ -194,7 +194,9 @@ def main():
     from repro.core.family import CNNFamily
     from repro.core.passes import Trainer
     from repro.data import SyntheticImages
+    from repro.launch.compile_cache import use_compile_cache
 
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument('--config', default='resnet8-cifar',
                     choices=sorted(CNN_REGISTRY))

@@ -17,18 +17,13 @@ import functools
 import jax
 from jax.sharding import PartitionSpec as P
 
-try:                                    # jax>=0.6
-    from jax import shard_map as _shard_map
+from jax import shard_map as _shard_map
 
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False)
-except ImportError:                      # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map_old
 
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map_old(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=False)
+def shard_map(f, mesh, in_specs, out_specs):
+    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                      check_vma=False)
+
 
 from repro.models.attention import (decode_attn_reference,
                                     decode_mla_reference)

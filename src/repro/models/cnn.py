@@ -16,7 +16,10 @@ import math
 import jax
 import jax.numpy as jnp
 
-from repro.core.quantization import fake_quant_act, fake_quant_weight
+from repro.core.quantization import (fake_quant_act, fake_quant_act_codes,
+                                     fake_quant_weight,
+                                     fake_quant_weight_codes)
+from repro.kernels.ref import quant_conv_ref
 
 
 def _conv_init(key, kh, kw, cin, cout, dtype=jnp.float32):
@@ -45,6 +48,15 @@ def conv(p, x, *, stride=1, quant=(0, 0), groups=1, name=None):
         return conv(p['v'], h, quant=quant)
     w_bits, a_bits = quant
     w = p['w']
+    if w_bits and a_bits:
+        # the int8 kernels' order: convolve the integer codes, then scale
+        # by (sx * sw) — dequantizing first is the same math but rounds
+        # differently, and the next layer's requantize turns that last ulp
+        # into flipped codes between training and serving
+        wc, sw = fake_quant_weight_codes(w, w_bits)
+        xc, sx = fake_quant_act_codes(x, a_bits)
+        return quant_conv_ref(xc, wc, sx, sw, p['b'], stride=stride,
+                              groups=groups)
     if w_bits:
         w = fake_quant_weight(w, w_bits, axis=-1)
     if a_bits:

@@ -19,7 +19,8 @@ from repro.core.passes import ChainState
 from repro.data import SyntheticImages
 from repro.kernels import ops, ref
 from repro.kernels.quant_conv import im2col_nhwc, quant_conv
-from repro.kernels.tiling import fit_block, fit_or_pad, pad_to
+from repro.kernels.tiling import (LANE, MODELLED_KIND, device_peaks,
+                                  fit_block, fit_or_pad, pad_to)
 from repro.models.cnn import cnn_forward, init_cnn
 
 CONFIGS = {'resnet': RESNET8_CIFAR, 'vgg': VGG8_CIFAR,
@@ -486,6 +487,35 @@ def test_tiling_fit_block_and_padding():
         fit_block(64, 97)                        # prime: no silent 1-blocks
     assert fit_or_pad(64, 97) == (64, 128)
     assert pad_to(97) == 128 and pad_to(128) == 128
+
+
+@pytest.mark.parametrize('block,dim,want', [
+    (256, 576, (128, 640)),      # 3x3x64 im2col K: never the 192 Mosaic refuses
+    (256, 1152, (128, 1152)),    # 3x3x128: a 128-multiple divisor exists
+    (256, 512, (256, 512)),
+    (128, 10, (10, 10)),         # whole dim in one block is always legal
+])
+def test_tiling_lane_blocks_are_128_aligned(block, dim, want):
+    """Mosaic's rule for a lane-axis block: a multiple of 128 or the whole
+    dim.  fit_or_pad(align=LANE) pads instead of picking anything else."""
+    assert fit_or_pad(block, dim, align=LANE) == want
+    b, p = want
+    assert b == p or b % LANE == 0
+
+
+def test_device_peaks_table():
+    """One sourced peak table keyed by device_kind: the CPU gets the v5e
+    row labelled as modelled, an unknown TPU kind is an error."""
+    import types
+    row = device_peaks(jax.devices('cpu')[0])
+    assert row['modelled'] and row['kind'] == MODELLED_KIND
+    assert row['int8_ops'] == 2 * row['bf16_flops']
+    v5e = device_peaks(types.SimpleNamespace(platform='tpu',
+                                             device_kind='TPU v5 lite'))
+    assert not v5e['modelled'] and v5e['hbm_bytes_per_s'] == 819e9
+    with pytest.raises(KeyError, match='no published peaks'):
+        device_peaks(types.SimpleNamespace(platform='tpu',
+                                           device_kind='TPU v99'))
 
 
 def test_prime_dims_pad_through_kernels():
