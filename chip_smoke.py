@@ -41,6 +41,11 @@ import time
 
 ROOT = os.path.dirname(os.path.abspath(__file__))
 CONFIG = 'resnet34-cifar'
+BATCH = 128         # QAT and calibration batch
+REQUESTS = 256
+SLOTS = 64
+RATE = 8000.0       # Poisson arrival rate (requests/s)
+MAX_WAIT = 0.005    # run a partial batch once its oldest request waited (s)
 # served-vs-fp32 tolerance of the resident export tests
 # (tests/test_export.py: atol = 6e-2 * max(|reference|, 1))
 REF_ATOL = 6e-2
@@ -138,7 +143,7 @@ def poisson_trace(xs, rate, seed):
     return [Request(i, xs[i], float(t[i])) for i in range(xs.shape[0])]
 
 
-def one_chip(args):
+def one_chip(seed):
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -148,27 +153,27 @@ def one_chip(args):
     from repro.serving import ContinuousBatchScheduler
 
     (dev,) = tpu_devices(1)
-    fam, params, cfg = build(args.seed)
+    fam, params, cfg = build(seed)
 
     # (a) QAT through the chain's trainer
     losses = []
     t0 = time.perf_counter()
-    params, _ = Trainer(batch=args.batch, steps=3, seed=args.seed).fit(
+    params, _ = Trainer(batch=BATCH, steps=3, seed=seed).fit(
         fam, cfg, params, losses=losses)
     check(len(losses) == 3 and all(math.isfinite(v) for v in losses),
           f'QAT losses not finite: {losses}')
-    say('qat', config=cfg.name, steps=3, batch=args.batch,
+    say('qat', config=cfg.name, steps=3, batch=BATCH,
         losses=[round(v, 6) for v in losses], finite=True,
         seconds=round(time.perf_counter() - t0, 3))
 
     # (b) int8-resident export calibrated on one batch
-    calib = fam.eval_batches(1, args.batch, seed=args.seed + 100)[0][0]
+    calib = fam.eval_batches(1, BATCH, seed=seed + 100)[0][0]
     model, export_s = export(params, cfg, calib)
     s = model.summary()
     xs = jnp.concatenate([x for x, _ in fam.eval_batches(
-        -(-args.requests // args.batch), args.batch, seed=args.seed + 200)]
-    )[:args.requests]
-    compile_s = compile_stages(model, xs[:args.slots])
+        -(-REQUESTS // BATCH), BATCH, seed=seed + 200)]
+    )[:REQUESTS]
+    compile_s = compile_stages(model, xs[:SLOTS])
     say('export', backend=model.backend, stages=model.n_stages,
         kernel_launches=s['kernel_launches'],
         fused_lowrank=s['n_fused_lowrank'],
@@ -178,20 +183,20 @@ def one_chip(args):
         cold_compile_seconds=[round(v, 3) for v in compile_s])
 
     # (c) the request scheduler on the wall clock
-    threshold = calibrate_exit_threshold(model, xs[:args.slots])
-    sched = ContinuousBatchScheduler(model, slots=args.slots,
+    threshold = calibrate_exit_threshold(model, xs[:SLOTS])
+    sched = ContinuousBatchScheduler(model, slots=SLOTS,
                                      threshold=threshold,
-                                     max_wait=args.max_wait)
+                                     max_wait=MAX_WAIT)
     # warm every stage program off the clock: nothing exits at 2.0
-    ContinuousBatchScheduler(model, slots=args.slots, threshold=2.0
+    ContinuousBatchScheduler(model, slots=SLOTS, threshold=2.0
                              ).run_trace(poisson_trace(xs[:4], 1e3, 1))
     completions, metrics = sched.run_trace(
-        poisson_trace(xs, args.rate, args.seed))
+        poisson_trace(xs, RATE, seed))
     m = metrics.summary()
-    check(len(completions) == args.requests,
-          f'{len(completions)}/{args.requests} requests completed')
-    say('serve', requests=f'{len(completions)}/{args.requests}',
-        slots=sched.slots, rate_rps=args.rate,
+    check(len(completions) == REQUESTS,
+          f'{len(completions)}/{REQUESTS} requests completed')
+    say('serve', requests=f'{len(completions)}/{REQUESTS}',
+        slots=sched.slots, rate_rps=RATE,
         threshold=round(threshold, 6), clock='wall',
         throughput_rps=round(m['throughput_rps'], 1),
         p50_ms=round(m['p50_latency_s'] * 1e3, 3),
@@ -202,8 +207,8 @@ def one_chip(args):
     bad = oracle_mismatches(model, completions, xs, threshold, sched.slots)
     check(not bad, f'{len(bad)} requests differ from the monolithic '
                    f'oracle, first {bad[:8]}')
-    say('oracle', bit_exact=f'{args.requests - len(bad)}/{args.requests}')
-    xb = xs[:args.slots]
+    say('oracle', bit_exact=f'{REQUESTS - len(bad)}/{REQUESTS}')
+    xb = xs[:SLOTS]
     served = np.asarray(model.serve(xb))
     fp32 = cfg.replace(w_bits=0, a_bits=0)
     with jax.default_matmul_precision('float32'):
@@ -218,7 +223,7 @@ def one_chip(args):
     return dev
 
 
-def four_chips(args):
+def four_chips(seed):
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -227,13 +232,13 @@ def four_chips(args):
                                PipelineParallelScheduler)
 
     devs = tpu_devices(4)
-    fam, params, cfg = build(args.seed)
-    calib = fam.eval_batches(1, args.batch, seed=args.seed + 100)[0][0]
+    fam, params, cfg = build(seed)
+    calib = fam.eval_batches(1, BATCH, seed=seed + 100)[0][0]
     model, _ = export(params, cfg, calib)
     xs = jnp.concatenate([x for x, _ in fam.eval_batches(
-        -(-args.requests // args.batch), args.batch, seed=args.seed + 200)]
-    )[:args.requests]
-    x0 = xs[:args.slots]
+        -(-REQUESTS // BATCH), BATCH, seed=seed + 200)]
+    )[:REQUESTS]
+    x0 = xs[:SLOTS]
     compile_stages(model, x0)
     threshold = calibrate_exit_threshold(model, x0)
 
@@ -250,17 +255,17 @@ def four_chips(args):
         if k < model.n_stages - 1:
             carry = out[1]
 
-    trace = poisson_trace(xs, args.rate, args.seed)
-    single = ContinuousBatchScheduler(model, slots=args.slots,
+    trace = poisson_trace(xs, RATE, seed)
+    single = ContinuousBatchScheduler(model, slots=SLOTS,
                                       threshold=threshold, stage_costs=costs)
     s_comp, _ = single.run_trace(trace)
-    pipe = PipelineParallelScheduler(model, slots=args.slots,
+    pipe = PipelineParallelScheduler(model, slots=SLOTS,
                                      threshold=threshold, stage_costs=costs,
                                      devices=devs)
-    p_comp, _ = pipe.run_trace(poisson_trace(xs, args.rate, args.seed))
+    p_comp, _ = pipe.run_trace(poisson_trace(xs, RATE, seed))
     for name, comp in (('single', s_comp), ('pipeline', p_comp)):
-        check(len(comp) == args.requests,
-              f'{name}: {len(comp)}/{args.requests} completed')
+        check(len(comp) == REQUESTS,
+              f'{name}: {len(comp)}/{REQUESTS} completed')
 
     # placement: each stage's params and outputs on its named TPU device
     placed = pipe.model
@@ -291,16 +296,16 @@ def four_chips(args):
                 if p_comp[r.rid].exit_stage == s_comp[r.rid].exit_stage
                 and np.array_equal(p_comp[r.rid].logits,
                                    s_comp[r.rid].logits))
-    check(agree == args.requests,
+    check(agree == REQUESTS,
           f'pipeline and single-device disagree on '
-          f'{args.requests - agree} requests')
+          f'{REQUESTS - agree} requests')
     for name, comp in (('single', s_comp), ('pipeline', p_comp)):
-        bad = oracle_mismatches(model, comp, xs, threshold, args.slots)
+        bad = oracle_mismatches(model, comp, xs, threshold, SLOTS)
         check(not bad, f'{name}: {len(bad)} requests differ from the '
                        f'monolithic oracle, first {bad[:8]}')
-    say('pipeline', requests=args.requests, devices=len(devs),
+    say('pipeline', requests=REQUESTS, devices=len(devs),
         stage_costs_ms=[round(c * 1e3, 4) for c in costs],
-        pipeline_vs_single=f'{agree}/{args.requests}',
+        pipeline_vs_single=f'{agree}/{REQUESTS}',
         oracle_bit_exact='single,pipeline')
     return devs[0]
 
@@ -309,16 +314,8 @@ def main():
     ap = argparse.ArgumentParser(description=__doc__.split('\n')[0])
     ap.add_argument('--chips', type=int, default=1, choices=(1, 4),
                     help='4: only the pipeline-parallel phase, on 4 chips')
-    ap.add_argument('--seed', type=int, default=0)
-    ap.add_argument('--batch', type=int, default=128,
-                    help='QAT and calibration batch')
-    ap.add_argument('--requests', type=int, default=256)
-    ap.add_argument('--slots', type=int, default=64)
-    ap.add_argument('--rate', type=float, default=8000.0,
-                    help='Poisson arrival rate (requests/s)')
-    ap.add_argument('--max-wait', type=float, default=0.005,
-                    help='run a partial batch once its oldest request '
-                         'waited this long (s)')
+    ap.add_argument('--seed', type=int, default=0,
+                    help='seed of the random weights, data and trace')
     args = ap.parse_args()
 
     sys.path.insert(0, os.path.join(ROOT, 'src'))
@@ -327,7 +324,8 @@ def main():
     except ImportError as e:
         fail(f'the repository is not next to this script ({e})')
     use_compile_cache()
-    dev = four_chips(args) if args.chips == 4 else one_chip(args)
+    dev = (four_chips(args.seed) if args.chips == 4
+           else one_chip(args.seed))
     import jax
     print(json.dumps({'ok': True, 'device': {
         'platform': dev.platform, 'kind': dev.device_kind,

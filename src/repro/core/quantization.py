@@ -78,8 +78,8 @@ def fake_quant_weight(w: jax.Array, bits: int, *, axis: int | None = -1,
                       and bits > 1 and axis in (-1, 1))
     if use_kernel:
         return _kernel_fake_quant_ste(w, bits)
-    q, scale = quantize_weight(w, bits, axis=axis)
-    return _ste(q.astype(w.dtype) * scale.astype(w.dtype), w)
+    codes, scale = fake_quant_weight_codes(w, bits, axis=axis)
+    return codes * scale
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
@@ -100,44 +100,47 @@ def _kfq_bwd(bits, _res, g):
 _kernel_fake_quant_ste.defvjp(_kfq_fwd, _kfq_bwd)
 
 
-def fake_quant_weight_codes(w: jax.Array, bits: int):
-    """Per-out-channel (last axis) weight fake-quant split as (codes,
-    scale): ``codes * scale`` is :func:`fake_quant_weight`'s value.  The
-    codes are exact integers carrying the STE gradient; the scale is
-    constant to autodiff, as in :func:`fake_quant_weight`."""
-    q, scale = quantize_weight(w, bits, axis=-1)
+def fake_quant_weight_codes(w: jax.Array, bits: int, *, axis=-1):
+    """Weight fake-quant split as (codes, scale), on :func:`quantize_weight`'s
+    grid: ``codes * scale`` is :func:`fake_quant_weight`'s value.  The codes
+    are exact integers carrying the STE gradient; the scale (broadcast
+    shape) is constant to autodiff.  ``bits`` of 0 or >= 32 give ``(w, 1)``.
+    """
+    if bits <= 0 or bits >= 32:
+        return w, jnp.ones((), w.dtype)
+    q, scale = quantize_weight(w, bits, axis=axis)
     scale = jax.lax.stop_gradient(scale.astype(w.dtype))
     codes = _ste(q.astype(w.dtype), w / scale)
     # opaque to XLA's algebraic simplifier, like the export's precomputed
     # scale: otherwise it reassociates (amax_x * c) * (amax_w * c) in the
-    # epilogue and rounds the product differently from serving
-    return codes, jax.lax.optimization_barrier(scale.reshape(-1))
+    # conv epilogue and rounds the product differently from serving
+    return codes, jax.lax.optimization_barrier(scale)
 
 
-def fake_quant_act_codes(x: jax.Array, bits: int):
-    """Activation fake-quant split as (codes, scale), the per-tensor
-    dynamic grid of :func:`fake_quant_act`."""
+def fake_quant_act_codes(x: jax.Array, bits: int, *,
+                         amax: float | None = None):
+    """Activation fake-quant split as (codes, scale): symmetric uniform with
+    a per-tensor scale from ``amax`` (default: the batch's abs-max, the
+    paper's hardware-friendly 'fixed-point uniform' choice).  The scale is
+    constant to autodiff, which keeps QAT stable; the codes carry the STE
+    gradient.  ``bits`` of 0 or >= 32 give ``(x, 1)``."""
+    if bits <= 0 or bits >= 32:
+        return x, jnp.ones((), x.dtype)
     qmax = 2.0 ** (bits - 1) - 1.0
-    amax = jax.lax.stop_gradient(jnp.maximum(jnp.max(jnp.abs(x)), 1e-8))
-    s = amax * (1.0 / qmax)
+    s = jnp.max(jnp.abs(x)) if amax is None else jnp.asarray(amax, x.dtype)
+    # times the reciprocal, as in quantize_weight
+    s = jax.lax.stop_gradient(jnp.maximum(s, 1e-8)) * (1.0 / qmax)
     v = x / s
     return _ste(jnp.clip(jnp.round(v), -qmax - 1, qmax), v), s
 
 
 def fake_quant_act(x: jax.Array, bits: int, *, amax: float | None = None) -> jax.Array:
-    """Activation fake-quant: symmetric uniform with running-free abs-max clip.
-
-    Per-tensor dynamic scale (abs-max of the current batch) — matches the
-    hardware-friendly 'fixed-point uniform' choice in the paper; stop-gradient
-    on the scale keeps QAT stable.
-    """
+    """Activation fake-quant with STE: ``codes * scale`` of
+    :func:`fake_quant_act_codes`."""
     if bits <= 0 or bits >= 32:
         return x
-    qmax = 2.0 ** (bits - 1) - 1.0
-    s = jnp.max(jnp.abs(x)) if amax is None else jnp.asarray(amax, x.dtype)
-    s = jax.lax.stop_gradient(jnp.maximum(s, 1e-8)) * (1.0 / qmax)
-    xq = jnp.clip(jnp.round(x / s), -qmax - 1, qmax) * s
-    return _ste(xq.astype(x.dtype), x)
+    codes, s = fake_quant_act_codes(x, bits, amax=amax)
+    return (codes * s).astype(x.dtype)
 
 
 def quantize_params_for_serving(params, bits: int = 8):

@@ -38,13 +38,16 @@ def fake_quant_ref(w, bits: int):
 
 
 def _int_conv(x_q, w_q, stride, groups):
-    """Exact integer SAME conv of int8 codes, returned as fp32.
+    """SAME conv of integer codes, returned as fp32.
 
     fp32 holds every partial sum exactly while ``K * 128 * 128 < 2**24``
-    (K = KH*KW*per-group CIN), so small-K convs run XLA's fast fp32 conv on
-    the raw codes; larger K accumulates in int32 (exact at any K the
-    kernels serve).  Either way the result equals the kernels' int32
-    accumulator."""
+    (K = KH*KW*per-group CIN, i.e. K < 1024), so small-K convs run XLA's
+    fast fp32 conv on the raw codes.  Larger K accumulates int8 codes in
+    int32, exact at any K.  Either way int8 codes give the kernels' int32
+    accumulator exactly.  Float codes (the QAT forward, whose codes carry
+    gradients) always take the fp32 conv, so at K >= 1024 their partial
+    sums round like any fp32 conv and match the kernels only approximately.
+    """
     kh, kw, cg, _ = w_q.shape
     dt = (jnp.float32 if kh * kw * cg * 128 * 128 < 2 ** 24
           or x_q.dtype != jnp.int8 else jnp.int8)

@@ -16,8 +16,7 @@ import math
 import jax
 import jax.numpy as jnp
 
-from repro.core.quantization import (fake_quant_act, fake_quant_act_codes,
-                                     fake_quant_weight,
+from repro.core.quantization import (fake_quant_act_codes,
                                      fake_quant_weight_codes)
 from repro.kernels.ref import quant_conv_ref
 
@@ -46,26 +45,18 @@ def conv(p, x, *, stride=1, quant=(0, 0), groups=1, name=None):
     if 'u' in p:
         h = conv(p['u'], x, stride=stride, quant=quant, groups=groups)
         return conv(p['v'], h, quant=quant)
-    w_bits, a_bits = quant
-    w = p['w']
-    if w_bits and a_bits:
-        # the int8 kernels' order: convolve the integer codes, then scale
-        # by (sx * sw) — dequantizing first is the same math but rounds
-        # differently, and the next layer's requantize turns that last ulp
-        # into flipped codes between training and serving
-        wc, sw = fake_quant_weight_codes(w, w_bits)
-        xc, sx = fake_quant_act_codes(x, a_bits)
-        return quant_conv_ref(xc, wc, sx, sw, p['b'], stride=stride,
-                              groups=groups)
-    if w_bits:
-        w = fake_quant_weight(w, w_bits, axis=-1)
-    if a_bits:
-        x = fake_quant_act(x, a_bits)
-    y = jax.lax.conv_general_dilated(
-        x, w.astype(x.dtype), (stride, stride), 'SAME',
-        feature_group_count=groups,
-        dimension_numbers=('NHWC', 'HWIO', 'NHWC'))
-    return y + p['b'].astype(y.dtype)
+    # the int8 kernels' order: convolve the integer codes, then scale by
+    # (sx * sw) -- dequantizing first is the same math but rounds
+    # differently, and the next layer's requantize can turn that last ulp
+    # into flipped codes between training and serving.  The codes conv is
+    # exact only below K = 1024 (ref._int_conv); above it fp32 partial
+    # sums round, so training tracks serving closely but not bit for bit.
+    # An operand that is not quantized rides with scale 1, so fp32
+    # training is a plain conv.
+    wc, sw = fake_quant_weight_codes(p['w'], quant[0])
+    xc, sx = fake_quant_act_codes(x, quant[1])
+    return quant_conv_ref(xc, wc, sx, sw.reshape(-1), p['b'], stride=stride,
+                          groups=groups)
 
 
 def out_channels(p) -> int:
@@ -101,14 +92,10 @@ def fc(p, x, *, quant=(0, 0), name=None):
     del name
     if 'u' in p:                   # low-rank factored: two chained matmuls
         return fc(p['v'], fc(p['u'], x, quant=quant), quant=quant)
-    w_bits, a_bits = quant
-    w = p['w']
-    if w_bits:
-        w = fake_quant_weight(w, w_bits, axis=-1)
-    if a_bits:
-        x = fake_quant_act(x, a_bits)
-    y = x @ w.astype(x.dtype)
-    return y + p['b'].astype(x.dtype) if 'b' in p else y
+    wc, sw = fake_quant_weight_codes(p['w'], quant[0])
+    xc, sx = fake_quant_act_codes(x, quant[1])
+    y = (xc @ wc) * (sx * sw.reshape(-1))        # conv's order, as served
+    return y + p['b'] if 'b' in p else y
 
 
 # ------------------------------------------------------------------------ init
