@@ -68,14 +68,17 @@ def im2col_nhwc(x, kh: int, kw: int, stride: int = 1):
     ``w.reshape(KH*KW*C, COUT)`` for HWIO weights.  Works on any dtype; the
     int8 serving path feeds already-quantized activations so the zero pad
     is exact.  Lowered as one gather over the padded spatial plane with
-    cached (per-geometry) indices — a pure memory-layout op.
+    cached (per-geometry) indices — a pure memory-layout op, under
+    ``jax.named_scope('im2col')`` so a device profile tells it from the
+    matmul.
     """
     B, H, W, C = x.shape
     (ph, pw), (oh, ow), idx = _im2col_plan(H, W, kh, kw, stride)
-    x = jnp.pad(x, ((0, 0), ph, pw, (0, 0)))
-    flat = x.reshape(B, x.shape[1] * x.shape[2], C)
-    patches = jnp.take(flat, jnp.asarray(idx), axis=1)
-    return patches.reshape(B * oh * ow, kh * kw * C), (oh, ow)
+    with jax.named_scope('im2col'):
+        x = jnp.pad(x, ((0, 0), ph, pw, (0, 0)))
+        flat = x.reshape(B, x.shape[1] * x.shape[2], C)
+        patches = jnp.take(flat, jnp.asarray(idx), axis=1)
+        return patches.reshape(B * oh * ow, kh * kw * C), (oh, ow)
 
 
 @functools.partial(jax.jit, static_argnames=('stride', 'relu', 'bm', 'bn',

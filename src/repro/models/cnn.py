@@ -169,36 +169,48 @@ def global_pool(x):
     return x.mean(axis=(1, 2))
 
 
+def _scoped(fn, name, *args, **kw):
+    """One injected layer call under ``jax.named_scope(name)``, so the
+    device ops it lowers to carry the layer's stable name in their
+    metadata (a profile charges them to the layer).  Metadata only: the
+    computation is unchanged."""
+    with jax.named_scope(name):
+        return fn(*args, name=name, **kw)
+
+
 def _block_forward(blk, x, kind, stride, quant, conv_fn, glue_fn,
                    name=''):
     if kind == 'resnet':
-        h = glue_fn(blk['n1'],
-                    conv_fn(blk['conv1'], x, stride=stride, quant=quant,
-                            name=f'{name}.conv1'),
-                    act='relu', name=f'{name}.n1')
-        y = conv_fn(blk['conv2'], h, quant=quant, name=f'{name}.conv2')
-        skip = conv_fn(blk['proj'], x, stride=stride, quant=quant,
-                       name=f'{name}.proj') if 'proj' in blk else x
-        return glue_fn(blk['n2'], y, act='relu', skip=skip,
-                       name=f'{name}.n2')
+        h = _scoped(glue_fn, f'{name}.n1', blk['n1'],
+                    _scoped(conv_fn, f'{name}.conv1', blk['conv1'], x,
+                            stride=stride, quant=quant),
+                    act='relu')
+        y = _scoped(conv_fn, f'{name}.conv2', blk['conv2'], h, quant=quant)
+        skip = _scoped(conv_fn, f'{name}.proj', blk['proj'], x,
+                       stride=stride, quant=quant) if 'proj' in blk else x
+        return _scoped(glue_fn, f'{name}.n2', blk['n2'], y, act='relu',
+                       skip=skip)
     if kind == 'vgg':
-        return glue_fn(blk['n1'],
-                       conv_fn(blk['conv1'], x, stride=stride, quant=quant,
-                               name=f'{name}.conv1'),
-                       act='relu', name=f'{name}.n1')
+        return _scoped(glue_fn, f'{name}.n1', blk['n1'],
+                       _scoped(conv_fn, f'{name}.conv1', blk['conv1'], x,
+                               stride=stride, quant=quant),
+                       act='relu')
     # mobilenet
     e = out_channels(blk['expand'])
-    h = glue_fn(blk['n1'], conv_fn(blk['expand'], x, quant=quant,
-                                   name=f'{name}.expand'),
-                act='relu6', name=f'{name}.n1')
-    h = glue_fn(blk['n2'], conv_fn(blk['dw'], h, stride=stride, quant=quant,
-                                   groups=e, name=f'{name}.dw'),
-                act='relu6', name=f'{name}.n2')
+    h = _scoped(glue_fn, f'{name}.n1', blk['n1'],
+                _scoped(conv_fn, f'{name}.expand', blk['expand'], x,
+                        quant=quant),
+                act='relu6')
+    h = _scoped(glue_fn, f'{name}.n2', blk['n2'],
+                _scoped(conv_fn, f'{name}.dw', blk['dw'], h, stride=stride,
+                        quant=quant, groups=e),
+                act='relu6')
     skip = x if (stride == 1
                  and x.shape[-1] == out_channels(blk['project'])) else None
-    return glue_fn(blk['n3'], conv_fn(blk['project'], h, quant=quant,
-                                      name=f'{name}.project'),
-                   skip=skip, name=f'{name}.n3')
+    return _scoped(glue_fn, f'{name}.n3', blk['n3'],
+                   _scoped(conv_fn, f'{name}.project', blk['project'], h,
+                           quant=quant),
+                   skip=skip)
 
 
 def cnn_forward(params, cfg, x, *, collect_exits=False, conv_fn=None,
@@ -212,7 +224,10 @@ def cnn_forward(params, cfg, x, *, collect_exits=False, conv_fn=None,
     core/export.py injects int8 serving layers over the same topology, so
     training and serving cannot drift structurally.  Each call site carries
     a stable ``name`` (``s{stage}b{block}.conv1`` etc.) so the export
-    layer-plan compiler can attach per-layer static activation scales.
+    layer-plan compiler can attach per-layer static activation scales, and
+    runs under ``jax.named_scope`` of that name (the pools under
+    ``exit{s}.pool`` / ``head.pool``), so a device profile charges each
+    op to its layer.
 
     ``start_stage``/``stop_stage`` make the forward *stage-resumable* (the
     serving scheduler's continuous-batching split, core/export.py
@@ -235,9 +250,10 @@ def cnn_forward(params, cfg, x, *, collect_exits=False, conv_fn=None,
     pool_fn = pool_fn or global_pool
     quant = (cfg.w_bits, cfg.a_bits)
     if start_stage == 0:
-        h = glue_fn(params['stem_norm'],
-                    conv_fn(params['stem'], x, quant=quant, name='stem'),
-                    act='relu', name='stem.norm')
+        h = _scoped(glue_fn, 'stem.norm', params['stem_norm'],
+                    _scoped(conv_fn, 'stem', params['stem'], x,
+                            quant=quant),
+                    act='relu')
     else:
         h = x                                     # carry from stage s-1
     exits = {}
@@ -251,13 +267,15 @@ def cnn_forward(params, cfg, x, *, collect_exits=False, conv_fn=None,
             h = _block_forward(blk, h, cfg.kind, stride, quant, conv_fn,
                                glue_fn, name=f's{s}b{b}')
         if collect_exits and 'exits' in params and str(s) in params['exits']:
-            feat = pool_fn(h)
-            exits[s] = fc_fn(params['exits'][str(s)], feat, quant=quant,
-                             name=f'exit{s}')
+            with jax.named_scope(f'exit{s}.pool'):
+                feat = pool_fn(h)
+            exits[s] = _scoped(fc_fn, f'exit{s}', params['exits'][str(s)],
+                               feat, quant=quant)
     if stop_stage is not None:
         return exits, h                           # mid-network segment
-    feat = pool_fn(h)
-    logits = fc_fn(params['head'], feat, quant=quant, name='head')
+    with jax.named_scope('head.pool'):
+        feat = pool_fn(h)
+    logits = _scoped(fc_fn, 'head', params['head'], feat, quant=quant)
     if collect_exits:
         return logits, exits
     return logits

@@ -1,39 +1,63 @@
 """Span tracing for the serving stack, with a Chrome-trace exporter.
 
 A :class:`Tracer` collects :class:`Span` records from a scheduler run
-(simulated clock) or an export (wall clock) — the span taxonomy is fixed
-(see ``serving/README.md``):
+(simulated clock for the batch-level spans, wall clock for the host
+spans) or an export (wall clock) — the span taxonomy is fixed (see
+``serving/README.md``):
 
-=====================  ========================================================
-``request.queue``      async span per request: arrival (or requeue after a
-                       kill) -> service start; lives on the request's cohort
-                       track, correlated by rid.
-``request.admit``      instant at an SLO admission decision (rejections).
-``stage.exec``         one executed segment batch on a replica/executor
-                       track, with ``stage``/``live``/``slots``/``rids``
-                       attributes (``killed=True`` when a chaos kill
-                       truncated it).
-``compaction``         instant after a non-final segment lands: how many
-                       slots exited vs survived.
-``failover.restore``   checkpoint restore of a replacement replica, on the
-                       NEW replica's track.
-``export.calibrate``   wall-clock span around the layer-plan compile.
-``kernel.launch``      one timed kernel execution during measure-mode
-                       selection (these spans ARE the measurement).
-=====================  ========================================================
+=========================  ====================================================
+``request.queue``          async span per request: arrival (or requeue after
+                           a kill) -> service start; lives on the request's
+                           cohort track, correlated by rid.
+``request.admit``          instant at an SLO admission decision (rejections).
+``stage.exec``             one executed segment batch on a replica/executor
+                           track, with ``stage``/``live``/``slots``/``rids``
+                           attributes (``killed=True`` when a chaos kill
+                           truncated it).
+``compaction``             instant after a non-final segment lands: how many
+                           slots exited vs survived.
+``failover.restore``       checkpoint restore of a replacement replica, on
+                           the NEW replica's track.
+``export.calibrate``       wall-clock span around the layer-plan compile.
+``kernel.launch``          one timed kernel execution during measure-mode
+                           selection (these spans ARE the measurement).
+``serve.trace``            host span: one ``run_trace`` call (the root).
+``serve.round``            host span: one scheduling round that runs a
+                           segment (``stage``/``live``); its self time is the
+                           policy's own cost.
+``serve.assemble``         host span: building a batch (``_gather_rows``),
+                           with children ``serve.assemble.parts`` /
+                           ``.concat`` (where fresh host rows upload) /
+                           ``.pad``.
+``serve.dispatch``         host span: the segment call (an async enqueue).
+``serve.sync``             host span: the ``block_until_ready`` wait.
+``serve.land``             host span: landing a segment's output
+                           (``_land``), with child ``serve.land.fetch``
+                           (exit confidence and the device->host copies).
+=========================  ====================================================
 
-Timestamps are float seconds on whichever clock produced them; serving
-spans (simulated clock) and export spans (wall clock) land in different
-trace *processes*, so the two timelines never mix on one track.
+Timestamps are float seconds on whichever clock produced them.  Spans
+opened with :meth:`Tracer.span` are wall-clock and have two sinks: the
+tracer's own ``Span`` list, and a ``jax.profiler.TraceAnnotation`` of the
+same name and args around the same body, so a JAX profiler session sees
+them on one timeline with the device ops.  With no profiler session an
+annotation is a cheap no-op.  The ``serve.*`` spans go to the ``host``
+track and the export spans to ``export``; scheduler spans on the
+simulated clock go to the ``serving``/``requests`` tracks.  Each group is
+its own trace *process*, so wall-clock and simulated timelines never mix
+on one track.
 
-:data:`NULL_TRACER` (a :class:`NullTracer`) is the default everywhere: its
-methods are no-ops that allocate nothing, so the uninstrumented hot path
-pays one attribute check (``tracer.enabled``) and no span bookkeeping.
+:data:`NULL_TRACER` (a :class:`NullTracer`) is the default everywhere: it
+records nothing, and its :meth:`~NullTracer.span` opens only the profiler
+annotation, so the program's host spans reach a profiler session even
+when no tracer is attached.  The uninstrumented hot path pays one
+attribute check (``tracer.enabled``) per simulated-clock record and one
+annotation per host span.
 
 ``to_chrome()`` emits the Chrome trace-event JSON format (the ``'X'`` /
-``'b'``/``'e'`` / ``'i'`` / ``'C'`` phases) that https://ui.perfetto.dev
-and chrome://tracing load directly: one thread per replica, one per
-request cohort, grouped into ``serving`` / ``requests`` / ``export``
+``'b'``/``'e'`` / ``'i'`` phases) that https://ui.perfetto.dev and
+chrome://tracing load directly: one thread per replica, one per request
+cohort, grouped into ``serving`` / ``requests`` / ``export`` / ``host``
 processes.  :func:`load_chrome_trace` parses that JSON back into spans so
 a written trace file is a checkable artifact
 (:func:`repro.obs.validate.check_trace`), not just a picture.
@@ -46,21 +70,23 @@ import time
 from contextlib import contextmanager
 from dataclasses import dataclass, field
 
+from jax.profiler import TraceAnnotation
+
 SPAN = 'span'          # nested duration on one track
 ASYNC = 'async'        # request-lifetime span, correlated by cid (rid)
 INSTANT = 'instant'    # point event
-COUNTER = 'counter'    # sampled value (rendered as a counter track)
 
 # track-name prefix -> (pid, process name); unknown prefixes go to 'misc'
 _PID_GROUPS = (('replica', 1, 'serving'), ('executor', 1, 'serving'),
                ('device', 1, 'serving'), ('scheduler', 1, 'serving'),
-               ('cohort', 2, 'requests'), ('export', 3, 'export'))
+               ('cohort', 2, 'requests'), ('export', 3, 'export'),
+               ('host', 4, 'host'))
 
 
 @dataclass(frozen=True)
 class Span:
-    """One trace event: a duration (``kind='span'``/``'async'``), an
-    instant (``t1 == t0``), or a counter sample (``args={'value': v}``)."""
+    """One trace event: a duration (``kind='span'``/``'async'``) or an
+    instant (``t1 == t0``)."""
     name: str
     t0: float
     t1: float
@@ -100,16 +126,14 @@ class Tracer:
         self.spans.append(Span(name, float(t), float(t), track,
                                kind=INSTANT, args=args))
 
-    def counter(self, name, t, value, *, track='counters') -> None:
-        self.spans.append(Span(name, float(t), float(t), track,
-                               kind=COUNTER, args={'value': float(value)}))
-
     @contextmanager
     def span(self, name, *, track, **args):
-        """Wall-clock duration span around a ``with`` body."""
+        """Wall-clock duration span around a ``with`` body, recorded here
+        and opened as a profiler annotation of the same name and args."""
         t0 = self.now()
         try:
-            yield
+            with TraceAnnotation(name, **args):
+                yield
         finally:
             self.add(name, t0, self.now(), track=track, **args)
 
@@ -124,7 +148,8 @@ class Tracer:
 
 
 class NullTracer(Tracer):
-    """The default: every method is an allocation-free no-op."""
+    """The default: records nothing; ``span`` opens only the profiler
+    annotation."""
 
     enabled = False
 
@@ -143,12 +168,9 @@ class NullTracer(Tracer):
     def instant(self, name, t, *, track, **args):
         pass
 
-    def counter(self, name, t, value, *, track='counters'):
-        pass
-
-    @contextmanager
     def span(self, name, *, track, **args):
-        yield
+        """The profiler annotation alone: no span is recorded."""
+        return TraceAnnotation(name, **args)
 
     def to_chrome(self):
         return spans_to_chrome(())
@@ -212,10 +234,6 @@ def spans_to_chrome(spans) -> dict:
                            'id': cid, 'args': {}})
         elif s.kind == INSTANT:
             events.append({**base, 'ph': 'i', 's': 't'})
-        elif s.kind == COUNTER:
-            events.append({'name': s.name, 'pid': pid, 'tid': tid,
-                           'ts': s.t0 * 1e6, 'ph': 'C',
-                           'args': {s.name: s.args.get('value', 0.0)}})
     return {'traceEvents': events, 'displayTimeUnit': 'ms'}
 
 
@@ -265,11 +283,6 @@ def load_chrome_trace(path_or_dict) -> list[Span]:
         elif ph == 'i':
             spans.append(Span(e['name'], t, t, track(e), kind=INSTANT,
                               args=dict(e.get('args', {}))))
-        elif ph == 'C':
-            args = dict(e.get('args', {}))
-            v = args.get(e['name'], next(iter(args.values()), 0.0))
-            spans.append(Span(e['name'], t, t, track(e), kind=COUNTER,
-                              args={'value': float(v)}))
     if open_async:
         raise ValueError(f'torn async span(s): begin with no end for '
                          f'{sorted(open_async)}')

@@ -18,7 +18,10 @@ resilience event (replica kill, failover, straggler flag, scale up/down);
   mix** (requests the SLO layer force-exited at an earlier head), and
   ``n_late`` — by the never-late contract this must be 0;
 * resilience counters: ``failovers``, ``kills``, ``straggler_flags``,
-  ``scale_ups``/``scale_downs``, peak replica count.
+  ``scale_ups``/``scale_downs``, peak replica count;
+* batch-assembly transfer counters, always on: ``n_uploads`` (host->device
+  transfers: fresh host rows and survivor index arrays), ``upload_bytes``
+  and ``n_takes`` (survivor gathers).
 
 Beyond the aggregates, the instance keeps *timestamped* samples —
 ``(t_done, latency)`` per completion, ``(t, stage, live, slots, cost)``
@@ -70,6 +73,9 @@ class ServingMetrics:
     # ^ (t, stage_idx, live, slots, cost) — only when the scheduler passes t
     gauges: dict = field(default_factory=dict)    # name -> [(t, value)]
     device_samples: list = field(default_factory=list)  # (t, cost, device)
+    n_uploads: int = 0          # host->device transfers batch assembly issued
+    upload_bytes: int = 0
+    n_takes: int = 0            # survivor gathers (one per gathered leaf)
     n_deadline: int = 0
     n_on_time: int = 0
     n_late: int = 0
@@ -104,11 +110,18 @@ class ServingMetrics:
     def record_batch(self, stage_idx: int, live: int, slots: int,
                      t: float | None = None,
                      cost: float | None = None,
-                     device: int | None = None) -> None:
+                     device: int | None = None,
+                     transfers: dict | None = None) -> None:
         """``device`` (the pipeline scheduler passes its device ordinal)
         additionally feeds the per-device busy series behind
-        :meth:`device_occupancy`."""
+        :meth:`device_occupancy`; ``transfers`` (what the batch's
+        ``_gather_rows`` issued) adds to ``n_uploads``, ``upload_bytes``
+        and ``n_takes``."""
         self.batches.append((stage_idx, live, slots))
+        if transfers is not None:
+            self.n_uploads += transfers['n_uploads']
+            self.upload_bytes += transfers['upload_bytes']
+            self.n_takes += transfers['n_takes']
         if t is not None:
             self.batch_samples.append((t, stage_idx, live, slots,
                                        0.0 if cost is None else cost))
@@ -168,6 +181,9 @@ class ServingMetrics:
             'batch_occupancy': {
                 str(s): round(sum(occ[s]) / (len(occ[s]) * slots[s]), 4)
                 for s in stages if occ[s]},
+            'n_uploads': self.n_uploads,
+            'upload_bytes': self.upload_bytes,
+            'n_takes': self.n_takes,
             'availability': round(n / offered, 4) if offered else 0.0,
             'n_rejected': len(self.rejections),
             'n_degraded': len(self.degraded_stages),

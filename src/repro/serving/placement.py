@@ -245,6 +245,7 @@ class _Flight:
     src_devs: tuple = ()
     nbytes: int = 0
     t_kill: float | None = None
+    transfers: dict | None = None     # what assembling its batch issued
 
     @property
     def t_land(self) -> float:
@@ -387,11 +388,13 @@ class PipelineParallelScheduler(ContinuousBatchScheduler):
                         src_ords.add(o)
                     moved[id(src)] = jax.device_put(src, dev)
                 sources.append((moved[id(src)], idx))
-            batch = _gather_rows(sources, self.slots)
+            batch, transfers = _gather_rows(sources, self.slots,
+                                            self.tracer)
         else:
-            batch = jax.device_put(
-                _gather_rows([(src, idx) for _, src, idx, *_ in items],
-                             self.slots), dev)
+            batch, transfers = _gather_rows(
+                [(src, idx) for _, src, idx, *_ in items], self.slots,
+                self.tracer)
+            batch = jax.device_put(batch, dev)
         nbytes = sum(leaf.size * leaf.dtype.itemsize
                      for leaf in jax.tree.leaves(batch))
         out = jax.block_until_ready(self.model.run_stage(k, batch))
@@ -400,7 +403,8 @@ class PipelineParallelScheduler(ContinuousBatchScheduler):
                         if src_ords else 0.0)
         fl = _Flight(seq=self._seq, dev=d, k=k, items=items, out=out,
                      t_dispatch=now, t_exec=t_exec, t_end=t_exec + cost,
-                     src_devs=tuple(sorted(src_ords)), nbytes=nbytes)
+                     src_devs=tuple(sorted(src_ords)), nbytes=nbytes,
+                     transfers=transfers)
         self._seq += 1
         self._free_at[d] = fl.t_end
         return fl
@@ -445,7 +449,8 @@ class PipelineParallelScheduler(ContinuousBatchScheduler):
                 live=len(fl.items), slots=self.slots,
                 rids=[it[0].rid for it in fl.items])
         metrics.record_batch(fl.k, len(fl.items), self.slots, t=fl.t_exec,
-                             cost=fl.t_end - fl.t_exec, device=fl.dev)
+                             cost=fl.t_end - fl.t_exec, device=fl.dev,
+                             transfers=fl.transfers)
         self._land(fl.k, fl.items, fl.out, t, pend, completions, metrics,
                    track=track)
 

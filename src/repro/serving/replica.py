@@ -115,6 +115,7 @@ class _Flight:
     t_start: float
     t_end: float
     t_kill: float | None = None
+    transfers: dict | None = None     # what assembling its batch issued
 
     @property
     def t_land(self) -> float:
@@ -256,13 +257,15 @@ class ReplicaPoolScheduler(ContinuousBatchScheduler):
                 req.t_start = now
             if self.tracer.enabled:
                 self._trace_dispatch(items, now)
-        batch = _gather_rows([(src, idx) for _, src, idx, *_ in items],
-                             self.slots)
+        batch, transfers = _gather_rows(
+            [(src, idx) for _, src, idx, *_ in items], self.slots,
+            self.tracer)
         out = jax.block_until_ready(replica.model.run_stage(k, batch))
         slow = self.chaos.slow_factor(replica.rid, now)
         cost = self.stage_costs[k] * slow
         fl = _Flight(seq=self._seq, replica=replica, k=k, items=items,
-                     out=out, t_start=now, t_end=now + cost)
+                     out=out, t_start=now, t_end=now + cost,
+                     transfers=transfers)
         self._seq += 1
         replica.free_at = fl.t_end
         replica.n_batches += 1
@@ -296,7 +299,8 @@ class ReplicaPoolScheduler(ContinuousBatchScheduler):
                             slots=self.slots,
                             rids=[it[0].rid for it in fl.items])
         metrics.record_batch(fl.k, len(fl.items), self.slots,
-                             t=fl.t_start, cost=fl.t_end - fl.t_start)
+                             t=fl.t_start, cost=fl.t_end - fl.t_start,
+                             transfers=fl.transfers)
         self._land(fl.k, fl.items, fl.out, t, pend, completions, metrics,
                    track=track)
         expected = self.stage_costs[fl.k]

@@ -49,6 +49,15 @@ injects measured per-segment batch costs (the benchmark's simulated clock
 — medians, so a noisy box cannot corrupt the A/B); ``stage_costs=None``
 uses real wall time per executed batch.  Arrival timestamps gate
 admission either way, so a Poisson trace replays faithfully.
+
+Host work is traced on the wall clock, whatever the scheduler's clock:
+``serve.trace`` / ``serve.round`` / ``serve.assemble`` / ``serve.dispatch``
+/ ``serve.sync`` / ``serve.land`` spans on the tracer's ``host`` track,
+each also a ``jax.profiler`` annotation (serving/README.md), so a profiler
+session sees them beside the device ops with or without a tracer.
+``_gather_rows`` counts the host->device uploads and survivor takes it
+issues, and every ``record_batch`` adds them to the run's
+:class:`ServingMetrics`.
 """
 from __future__ import annotations
 
@@ -61,7 +70,7 @@ import numpy as np
 
 from repro.core.export import exit_confidence
 from repro.kernels.tiling import batch_slots
-from repro.obs.trace import as_tracer
+from repro.obs.trace import NULL_TRACER, as_tracer
 from repro.serving.metrics import ServingMetrics
 from repro.serving.request import Completion, RequestQueue
 
@@ -83,7 +92,7 @@ def exit_decisions(logits, exits, threshold):
     return stage, ans
 
 
-def _gather_rows(sources, slots):
+def _gather_rows(sources, slots, tracer=NULL_TRACER):
     """Assemble a batch padded to exactly ``slots`` from per-sample
     ``(src, idx)`` references — ``idx=None`` means ``src`` IS the sample
     (a fresh request's x), otherwise ``src`` is a batch pytree (array or
@@ -91,30 +100,53 @@ def _gather_rows(sources, slots):
     batch (one round's compacted survivors) gather with ONE indexed take
     per pytree leaf instead of O(slots) per-row slices.  The fixed
     geometry keeps one compiled program per stage and slot results
-    independent of occupancy."""
-    groups = []                          # (src, [idx...]) runs, or (row,)
-    for src, idx in sources:
-        if idx is None:
-            groups.append((src, None))
-        elif groups and groups[-1][1] is not None \
-                and groups[-1][0] is src:
-            groups[-1][1].append(idx)
-        else:
-            groups.append((src, [idx]))
-    parts = []
-    for src, idxs in groups:
-        if idxs is None:
-            parts.append(jax.tree.map(lambda a: a[None], src))
-        else:
-            arr = jnp.asarray(idxs)
-            parts.append(jax.tree.map(lambda a: a[arr], src))
-    batch = (parts[0] if len(parts) == 1
-             else jax.tree.map(lambda *ps: jnp.concatenate(ps), *parts))
-    return jax.tree.map(
-        lambda a: jnp.concatenate(
-            [a, jnp.zeros((slots - a.shape[0],) + a.shape[1:], a.dtype)])
-        if a.shape[0] < slots else a,
-        batch)
+    independent of occupancy.
+
+    Returns ``(batch, transfers)``: ``transfers`` counts what the call
+    issues, ``{'n_uploads', 'upload_bytes', 'n_takes'}`` — one upload per
+    fresh host (non-device) leaf and per survivor index array, one take
+    per gathered leaf."""
+    with tracer.span('serve.assemble', track='host',
+                     n_sources=len(sources)):
+        n_up = n_bytes = n_takes = 0
+        with tracer.span('serve.assemble.parts', track='host'):
+            groups = []                  # (src, [idx...]) runs, or (row,)
+            for src, idx in sources:
+                if idx is None:
+                    groups.append((src, None))
+                elif groups and groups[-1][1] is not None \
+                        and groups[-1][0] is src:
+                    groups[-1][1].append(idx)
+                else:
+                    groups.append((src, [idx]))
+            parts = []
+            for src, idxs in groups:
+                leaves, treedef = jax.tree.flatten(src)
+                if idxs is None:
+                    for a in leaves:
+                        if not isinstance(a, jax.Array):     # host row
+                            n_up += 1
+                            n_bytes += a.nbytes
+                    parts.append(treedef.unflatten([a[None] for a in leaves]))
+                else:
+                    arr = jnp.asarray(idxs)
+                    n_up += 1
+                    n_bytes += arr.nbytes
+                    n_takes += len(leaves)
+                    parts.append(treedef.unflatten([a[arr] for a in leaves]))
+        with tracer.span('serve.assemble.concat', track='host'):
+            batch = (parts[0] if len(parts) == 1
+                     else jax.tree.map(lambda *ps: jnp.concatenate(ps),
+                                       *parts))
+        with tracer.span('serve.assemble.pad', track='host'):
+            batch = jax.tree.map(
+                lambda a: jnp.concatenate(
+                    [a, jnp.zeros((slots - a.shape[0],) + a.shape[1:],
+                                  a.dtype)])
+                if a.shape[0] < slots else a,
+                batch)
+    return batch, {'n_uploads': n_up, 'upload_bytes': n_bytes,
+                   'n_takes': n_takes}
 
 
 class _Clock:
@@ -215,28 +247,32 @@ class ContinuousBatchScheduler:
         promote survivors (carry reference + their declined head's logits)
         to ``pend[k + 1]``.  Shared with the replica pool, which lands
         flights asynchronously."""
-        if k < self.n_segs - 1:
-            exits, carry = out
-            s = self.model.stage_exits[k]
-            conf = np.asarray(exit_confidence(exits[s]))
-            head = np.asarray(exits[s], np.float32)
-            n_exit = 0
-            for i, (req, *_) in enumerate(items):
-                if conf[i] > self.threshold:
-                    n_exit += 1
-                    self._complete(req, head[i], s, now, completions,
+        with self.tracer.span('serve.land', track='host', stage=k):
+            if k < self.n_segs - 1:
+                exits, carry = out
+                s = self.model.stage_exits[k]
+                with self.tracer.span('serve.land.fetch', track='host'):
+                    conf = np.asarray(exit_confidence(exits[s]))
+                    head = np.asarray(exits[s], np.float32)
+                n_exit = 0
+                for i, (req, *_) in enumerate(items):
+                    if conf[i] > self.threshold:
+                        n_exit += 1
+                        self._complete(req, head[i], s, now, completions,
+                                       metrics)
+                    else:                     # compact: reference the row
+                        pend[k + 1].append((req, carry, i, s, head[i]))
+                if self.tracer.enabled:
+                    self.tracer.instant(
+                        'compaction', now, track=track or self._track,
+                        stage=k, n_exit=n_exit,
+                        n_survive=len(items) - n_exit)
+            else:
+                with self.tracer.span('serve.land.fetch', track='host'):
+                    logits = np.asarray(out, np.float32)
+                for i, (req, *_) in enumerate(items):
+                    self._complete(req, logits[i], -1, now, completions,
                                    metrics)
-                else:                         # compact: reference the row
-                    pend[k + 1].append((req, carry, i, s, head[i]))
-            if self.tracer.enabled:
-                self.tracer.instant(
-                    'compaction', now, track=track or self._track,
-                    stage=k, n_exit=n_exit, n_survive=len(items) - n_exit)
-        else:
-            logits = np.asarray(out, np.float32)
-            for i, (req, *_) in enumerate(items):
-                self._complete(req, logits[i], -1, now, completions,
-                               metrics)
 
     def _trace_dispatch(self, items, now):
         """Close each request's queue span: the wait ends NOW (the span
@@ -257,13 +293,16 @@ class ContinuousBatchScheduler:
                 req.t_start = now             # service starts; wait ends
             if self.tracer.enabled:
                 self._trace_dispatch(items, now)
-        batch = _gather_rows([(src, idx) for _, src, idx, *_ in items],
-                             self.slots)
+        batch, transfers = _gather_rows(
+            [(src, idx) for _, src, idx, *_ in items], self.slots,
+            self.tracer)
         out = []
 
         def execute():
-            out.append(jax.block_until_ready(
-                self.model.run_stage(k, batch)))
+            with self.tracer.span('serve.dispatch', track='host', stage=k):
+                o = self.model.run_stage(k, batch)
+            with self.tracer.span('serve.sync', track='host', stage=k):
+                out.append(jax.block_until_ready(o))
         cost = self._clock.charge(k, execute)
         if self.tracer.enabled:
             self.tracer.add(
@@ -274,7 +313,7 @@ class ContinuousBatchScheduler:
         if self.slo is not None:
             self.slo.observe(k, cost)
         metrics.record_batch(k, len(items), self.slots, t=now - cost,
-                             cost=cost)
+                             cost=cost, transfers=transfers)
         self._land(k, items, out[0], now, pend, completions, metrics)
         return now
 
@@ -332,24 +371,20 @@ class ContinuousBatchScheduler:
             buf.clear()
             buf.extend(kept)
 
-    def run_trace(self, requests):
-        """Serve a whole arrival trace; returns ``({rid: Completion},
-        ServingMetrics)``.  Terminates exactly when every request has
-        completed or been rejected (the queue and every stage buffer
-        drained)."""
-        queue = RequestQueue(requests)
-        pend = [deque() for _ in range(self.n_segs)]
-        completions, metrics = {}, ServingMetrics()
-        now = queue.next_arrival() or 0.0
-        last_depth = None
+    def _next_round(self, queue, pend, completions, metrics, now):
+        """Admit what has arrived, sample the queue depth, and pick the
+        next segment to run, advancing the clock while the policy waits
+        (and resolving SLO deadlines before the charge).  Returns ``(k,
+        now)`` with ``pend[k]`` non-empty, or ``(None, now)`` once every
+        request has completed or been rejected."""
         while queue or any(pend):
             for r in queue.pop_ready(now, self.slots - len(pend[0])):
                 if self._admit(r, now, pend, metrics):
                     pend[0].append((r, r.x, None, None, None))
             depth = len(pend[0]) + queue.n_ready(now)
-            if depth != last_depth:
+            seen = metrics.gauges.get('queue_depth')
+            if not seen or seen[-1][1] != depth:
                 metrics.record_gauge('queue_depth', now, depth)
-                last_depth = depth
             k = self._pick(pend, more_arrivals=bool(queue), now=now)
             if self.slo is not None:
                 urgent = self.slo.urgent_segment(pend, now)
@@ -373,7 +408,34 @@ class ContinuousBatchScheduler:
                 self._slo_degrade(pend, k, now, completions, metrics)
                 if not pend[k]:               # the sweep emptied the batch
                     continue
-            now = self._run_segment(k, pend, completions, metrics, now)
+            return k, now
+        return None, now
+
+    def run_trace(self, requests):
+        """Serve a whole arrival trace; returns ``({rid: Completion},
+        ServingMetrics)``.  Terminates exactly when every request has
+        completed or been rejected (the queue and every stage buffer
+        drained).
+
+        Host spans (wall clock, profiler annotations too): ``serve.trace``
+        around the call, one ``serve.round`` per segment run, from the
+        dispatch of its batch to the pick of the next."""
+        queue = RequestQueue(requests)
+        pend = [deque() for _ in range(self.n_segs)]
+        completions, metrics = {}, ServingMetrics()
+        with self.tracer.span('serve.trace', track='host',
+                              n_requests=len(queue)):
+            now = queue.next_arrival() or 0.0
+            k, now = self._next_round(queue, pend, completions, metrics,
+                                      now)
+            while k is not None:
+                with self.tracer.span(
+                        'serve.round', track='host', stage=k,
+                        live=min(len(pend[k]), self.slots)):
+                    now = self._run_segment(k, pend, completions, metrics,
+                                            now)
+                    k, now = self._next_round(queue, pend, completions,
+                                              metrics, now)
         return completions, metrics
 
 
@@ -415,7 +477,8 @@ class StaticBatchScheduler:
                         'request.queue', req.t_arrival, now,
                         track=f'cohort{req.rid // self.slots}',
                         cid=req.rid, rid=req.rid)
-            batch = _gather_rows([(r.x, None) for r in ready], self.slots)
+            batch, transfers = _gather_rows([(r.x, None) for r in ready],
+                                            self.slots, self.tracer)
             out = []
 
             def execute():
@@ -429,7 +492,7 @@ class StaticBatchScheduler:
                                 rids=[r.rid for r in ready])
             now += cost
             metrics.record_batch(0, len(ready), self.slots, t=now - cost,
-                                 cost=cost)
+                                 cost=cost, transfers=transfers)
             logits, exits = out[0]
             stage, ans = exit_decisions(logits, exits, self.threshold)
             for i, req in enumerate(ready):

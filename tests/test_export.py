@@ -4,6 +4,9 @@ quant_conv kernel must match its lax.conv oracle in interpret mode.
 The int8-resident plan (``calibrate=...``) additionally must keep
 inter-layer activations int8 at every kernel boundary, never run an
 activation abs-max, and serve factored conv pairs as single launches."""
+import contextlib
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -422,6 +425,50 @@ def test_quant_conv_1x1_and_no_bias():
     expect = ref.quant_conv_ref(x_q, w_q, sx, sw, stride=2)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                rtol=1e-4, atol=1e-4)
+
+
+def _scopes(hlo_text):
+    """The layer scopes among compiled ops' ``op_name`` metadata paths."""
+    return set(re.findall(r'op_name="[^"]*?/(s0b0\.conv1|im2col)/',
+                          hlo_text))
+
+
+def test_export_segment_ops_carry_layer_scopes(monkeypatch):
+    """A segment program's ops carry their layer's name scope (the conv's
+    stable name, and ``im2col`` for its patch gather), and the scopes are
+    metadata only: the same plan lowered without them answers bit for
+    bit the same."""
+    from repro.core import export as export_lib
+    _, params, cfg = _with_exits(RESNET8_CIFAR)
+    x = jax.random.normal(jax.random.key(3), (8, 16, 16, 3))
+    model = export_cnn(params, cfg, use_pallas=True, calibrate=x)
+    fn = model.stage_fns[0]
+    assert _scopes(fn.lower(model.params, x).compile().as_text()) == {
+        's0b0.conv1', 'im2col'}
+    want = jax.block_until_ready(fn(model.params, x))
+    conv_fn, fc_fn, glue_fn, pool_fn = export_lib._resident_layers(
+        model.plan, True, qparams=model.params)
+    monkeypatch.setattr(jax, 'named_scope',
+                        lambda name: contextlib.nullcontext())
+    jax.clear_caches()                 # retrace the kernels' inner jits
+    try:
+        (bare, *_), _ = export_lib._make_stage_fns(
+            cfg, dict(conv_fn=conv_fn, fc_fn=fc_fn, glue_fn=glue_fn,
+                      pool_fn=pool_fn))
+        assert _scopes(bare.lower(model.params, x).compile()
+                       .as_text()) == set()
+        got = jax.block_until_ready(bare(model.params, x))
+    finally:
+        monkeypatch.undo()
+        jax.clear_caches()
+    (w_exits, w_carry), (g_exits, g_carry) = want, got
+    assert set(w_exits) == set(g_exits)
+    for s in w_exits:
+        np.testing.assert_array_equal(np.asarray(g_exits[s]),
+                                      np.asarray(w_exits[s]))
+    assert g_carry.scale == w_carry.scale
+    np.testing.assert_array_equal(np.asarray(g_carry.q),
+                                  np.asarray(w_carry.q))
 
 
 def test_im2col_matches_conv_patches():

@@ -54,7 +54,6 @@ def test_null_tracer_is_allocation_free_default():
     NULL_TRACER.add('x', 0, 1, track='t')
     NULL_TRACER.async_span('x', 0, 1, track='t', cid=0)
     NULL_TRACER.instant('x', 0, track='t')
-    NULL_TRACER.counter('x', 0, 1.0)
     with NULL_TRACER.span('x', track='t'):
         pass
     assert NULL_TRACER.spans == []
@@ -83,7 +82,6 @@ def test_chrome_roundtrip_all_kinds(tmp_path):
                  requeued=False)
     t.instant('compaction', 0.005, track='replica0', stage=0, n_exit=4,
               n_survive=4)
-    t.counter('queue_depth', 0.002, 3.0)
     path = str(tmp_path / 'trace.json')
     t.write(path)
     got = load_chrome_trace(path)
@@ -96,7 +94,6 @@ def test_chrome_roundtrip_all_kinds(tmp_path):
         assert g.t1 == pytest.approx(orig.t1, abs=1e-9)
     assert by_name['request.queue'].cid == 1
     assert by_name['stage.exec'].args['rids'] == [0, 1]
-    assert by_name['queue_depth'].args == {'value': 3.0}
     # process/thread structure: serving tracks in pid 1 in natural order
     # (replica10 after replica0), cohort in pid 2
     doc = json.load(open(path))
@@ -217,6 +214,90 @@ def test_pool_chaos_trace_shows_kill_and_failover(exported):
     qs = [s for s in tracer.spans
           if s.name == 'request.queue' and s.cid == rid]
     assert len(qs) == 2
+
+
+# ------------------------------------------------ host spans, two sinks
+
+_HOST = ('serve.trace', 'serve.round', 'serve.assemble',
+         'serve.assemble.parts', 'serve.assemble.concat',
+         'serve.assemble.pad', 'serve.dispatch', 'serve.sync', 'serve.land',
+         'serve.land.fetch')
+_PARENT = {'serve.round': 'serve.trace', 'serve.assemble': 'serve.round',
+           'serve.assemble.parts': 'serve.assemble',
+           'serve.assemble.concat': 'serve.assemble',
+           'serve.assemble.pad': 'serve.assemble',
+           'serve.dispatch': 'serve.round', 'serve.sync': 'serve.round',
+           'serve.land': 'serve.round', 'serve.land.fetch': 'serve.land'}
+
+
+def test_scheduler_host_spans_nest_on_the_host_track(exported):
+    """One serve.round per executed batch, each child inside its parent,
+    every serve.* span on the wall-clock host track, and the whole trace
+    (virtual-clock spans included) still valid after a Chrome round trip."""
+    model, thr = exported
+    reqs = _trace(3 * SLOTS + 5)
+    tracer = Tracer()
+    comp, met = ContinuousBatchScheduler(
+        model, slots=SLOTS, threshold=thr, stage_costs=COSTS,
+        tracer=tracer).run_trace(reqs)
+    host = [s for s in tracer.spans if s.name.startswith('serve.')]
+    assert {s.name for s in host} == set(_HOST)
+    assert all(s.track == 'host' for s in host)
+    rounds = [s for s in host if s.name == 'serve.round']
+    assert len(rounds) == len(met.batches)
+    assert [(s.args['stage'], s.args['live']) for s in rounds] == \
+        [(k, live) for k, live, _ in met.batches]
+    (root,) = [s for s in host if s.name == 'serve.trace']
+    assert root.args == {'n_requests': len(reqs)}
+    for s in host:
+        if s.name in _PARENT:
+            assert any(p.name == _PARENT[s.name] and p.t0 <= s.t0
+                       and s.t1 <= p.t1 for p in host), s
+    for name in ('serve.assemble', 'serve.dispatch', 'serve.land'):
+        assert sum(s.name == name for s in host) == len(met.batches)
+    doc = tracer.to_chrome()
+    procs = {e['pid']: e['args']['name'] for e in doc['traceEvents']
+             if e.get('ph') == 'M' and e['name'] == 'process_name'}
+    assert 'host' in procs.values()
+    spans = load_chrome_trace(doc)
+    assert check_trace(spans, comp, strict=True) == []
+    got = sorted((s.name, s.track) for s in spans
+                 if s.name.startswith('serve.'))
+    assert got == sorted((s.name, s.track) for s in host)
+
+
+@pytest.mark.parametrize('attached', [False, True])
+def test_null_tracer_span_records_nothing_but_reaches_the_profiler(
+        exported, tmp_path, attached):
+    """With no tracer attached the scheduler's host spans are profiler
+    annotations only: nothing is recorded, and a profiler session sees
+    them on its host plane.  An attached tracer feeds both sinks."""
+    model, thr = exported
+    with NULL_TRACER.span('serve.round', track='host', stage=0):
+        pass
+    assert NULL_TRACER.spans == []
+    tracer = Tracer() if attached else None
+    sched = ContinuousBatchScheduler(model, slots=SLOTS, threshold=thr,
+                                     stage_costs=COSTS, tracer=tracer)
+    reqs = _trace(SLOTS)
+    sched.run_trace(reqs)                  # compile outside the session
+    n0 = len(tracer.spans) if attached else 0
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        _, met = sched.run_trace(_trace(SLOTS))
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob('**/*.xplane.pb')
+    names = [e.name for p in jax.profiler.ProfileData.from_file(
+        str(path)).planes if p.name == '/host:CPU'
+        for line in p.lines for e in line.events]
+    assert set(_HOST) <= set(names)
+    assert names.count('serve.round') == len(met.batches)
+    if attached:
+        recorded = [sp.name for sp in tracer.spans[n0:]
+                    if sp.name.startswith('serve.')]
+        assert sorted(recorded) == sorted(n for n in names
+                                          if n.startswith('serve.'))
 
 
 # ------------------------------------------------------- analysis + gate

@@ -281,6 +281,52 @@ def test_metrics_percentiles_and_occupancy():
     assert percentile([], 99) == 0.0
 
 
+def test_gather_rows_counts_uploads_and_takes():
+    """What ``_gather_rows`` issues: one upload per fresh host row, none
+    for a row already on the device, and per survivor source batch one
+    index upload and one take per leaf."""
+    from repro.serving.scheduler import _gather_rows
+    rows = np.random.default_rng(0).normal(size=(SLOTS, 4, 4, 3)).astype(
+        np.float32)
+    batch, tr = _gather_rows([(r, None) for r in rows], SLOTS)
+    assert tr == {'n_uploads': SLOTS, 'upload_bytes': rows.nbytes,
+                  'n_takes': 0}
+    np.testing.assert_array_equal(np.asarray(batch), rows)
+    _, tr = _gather_rows([(jnp.asarray(rows[0]), None)], SLOTS)
+    assert tr == {'n_uploads': 0, 'upload_bytes': 0, 'n_takes': 0}
+    a, b = jnp.asarray(rows), QAct(jnp.asarray(rows, jnp.int8), 0.5)
+    c = jnp.asarray(rows) + 1
+    batch, tr = _gather_rows([(a, 0), (a, 3), (c, 1), (c, 2), (c, 5)],
+                             SLOTS)
+    assert tr == {'n_uploads': 2, 'upload_bytes': 5 * 4, 'n_takes': 2}
+    np.testing.assert_array_equal(
+        np.asarray(batch)[:5],
+        np.concatenate([rows[[0, 3]], rows[[1, 2, 5]] + 1]))
+    assert not np.asarray(batch)[5:].any()              # the pad
+    batch, tr = _gather_rows([(b, 1), (b, 2)], SLOTS)
+    assert isinstance(batch, QAct) and batch.scale == 0.5
+    assert tr == {'n_uploads': 1, 'upload_bytes': 2 * 4, 'n_takes': 1}
+
+
+def test_scheduler_transfer_counters_follow_batch_composition(exported):
+    """Fresh host rows upload once each at segment 0; with no exits every
+    later batch gathers one source batch's survivors: one index upload
+    and one take.  The totals ride on the run's metrics."""
+    model, _ = exported
+    rows = np.asarray(jax.random.normal(jax.random.key(9),
+                                        (2 * SLOTS, 32, 32, 3)))
+    reqs = [Request(i, rows[i], 0.0) for i in range(2 * SLOTS)]
+    _, met = ContinuousBatchScheduler(
+        model, slots=SLOTS, threshold=2.0,
+        stage_costs=[1e-3] * model.n_stages).run_trace(reqs)
+    later = 2 * (model.n_stages - 1)
+    s = met.summary()
+    assert s['n_batches'] == {str(k): 2 for k in range(model.n_stages)}
+    assert s['n_uploads'] == 2 * SLOTS + later
+    assert s['upload_bytes'] == rows.nbytes + later * SLOTS * 4
+    assert s['n_takes'] == later
+
+
 def test_latency_splits_into_queue_wait_and_execute(exported):
     """Both schedulers stamp Completion.t_start at first dispatch, so
     every latency decomposes exactly into queue-wait + execute and the
