@@ -456,8 +456,8 @@ def _measure_lowrank_selection(plan: LayerPlan, qparams, use_pallas: bool,
             f().block_until_ready()      # compile outside the clock
             ts = []
             for rep in range(reps):
-                t0 = time.perf_counter()
-                w0 = tracer.now()
+                w0 = tracer.now()        # no later than t0: spans never
+                t0 = time.perf_counter()  # overlap the next rep's
                 f().block_until_ready()
                 us = (time.perf_counter() - t0) * 1e6
                 tracer.add('kernel.launch', w0, w0 + us * 1e-6,

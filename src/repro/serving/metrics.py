@@ -20,8 +20,10 @@ resilience event (replica kill, failover, straggler flag, scale up/down);
 * resilience counters: ``failovers``, ``kills``, ``straggler_flags``,
   ``scale_ups``/``scale_downs``, peak replica count;
 * batch-assembly transfer counters, always on: ``n_uploads`` (host->device
-  transfers: fresh host rows and survivor index arrays), ``upload_bytes``
-  and ``n_takes`` (survivor gathers).
+  transfers issued: one per run of consecutive fresh host rows, stacked
+  into one upload, and one per survivor index array), ``upload_bytes``
+  (bytes transferred, host-side padding included) and ``n_takes``
+  (survivor gathers).
 
 Beyond the aggregates, the instance keeps *timestamped* samples —
 ``(t_done, latency)`` per completion, ``(t, stage, live, slots, cost)``

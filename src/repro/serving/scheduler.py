@@ -92,49 +92,77 @@ def exit_decisions(logits, exits, threshold):
     return stage, ans
 
 
+_HOST_RUN = object()       # group key: a run of consecutive fresh host rows
+
+
+def _upload_rows(rows, n):
+    """Stack fresh host rows into one batch of ``n >= len(rows)`` rows,
+    zero past the last row, on the host, and put it on the device in one
+    transfer per leaf.  Returns ``(batch, bytes transferred)``."""
+    stacked = []
+    for col in zip(*(jax.tree.leaves(r) for r in rows)):
+        a = np.zeros((n,) + np.shape(col[0]), np.result_type(col[0]))
+        np.stack(col, out=a[:len(col)])
+        stacked.append(a)
+    batch = jax.tree.structure(rows[0]).unflatten(jax.device_put(stacked))
+    return batch, sum(a.nbytes for a in stacked)
+
+
 def _gather_rows(sources, slots, tracer=NULL_TRACER):
     """Assemble a batch padded to exactly ``slots`` from per-sample
     ``(src, idx)`` references — ``idx=None`` means ``src`` IS the sample
     (a fresh request's x), otherwise ``src`` is a batch pytree (array or
     QAct) and ``idx`` a row in it.  Consecutive rows of the same source
     batch (one round's compacted survivors) gather with ONE indexed take
-    per pytree leaf instead of O(slots) per-row slices.  The fixed
-    geometry keeps one compiled program per stage and slot results
-    independent of occupancy.
+    per pytree leaf instead of O(slots) per-row slices.  Consecutive
+    fresh host rows (no leaf a ``jax.Array``) stack on the host and upload
+    as ONE transfer per leaf; a batch made only of host rows is
+    zero-padded to ``slots`` on the host before that transfer.  Fresh
+    rows already on the device join as they are.  Parts keep the
+    sources' order.  The fixed geometry keeps one compiled program per
+    stage and slot results independent of occupancy.
 
     Returns ``(batch, transfers)``: ``transfers`` counts what the call
     issues, ``{'n_uploads', 'upload_bytes', 'n_takes'}`` — one upload per
-    fresh host (non-device) leaf and per survivor index array, one take
-    per gathered leaf."""
+    leaf of each run of fresh host rows and per survivor index array,
+    with the bytes transferred (host padding included), and one take per
+    gathered leaf."""
     with tracer.span('serve.assemble', track='host',
                      n_sources=len(sources)):
         n_up = n_bytes = n_takes = 0
         with tracer.span('serve.assemble.parts', track='host'):
-            groups = []                  # (src, [idx...]) runs, or (row,)
+            groups = []   # (src, [idx..]), (_HOST_RUN, [row..]), (row, None)
             for src, idx in sources:
-                if idx is None:
-                    groups.append((src, None))
-                elif groups and groups[-1][1] is not None \
-                        and groups[-1][0] is src:
-                    groups[-1][1].append(idx)
+                if idx is None and any(isinstance(a, jax.Array)
+                                       for a in jax.tree.leaves(src)):
+                    groups.append((src, None))         # on the device
+                    continue
+                key, item = (_HOST_RUN, src) if idx is None else (src, idx)
+                if groups and groups[-1][1] is not None \
+                        and groups[-1][0] is key:
+                    groups[-1][1].append(item)
                 else:
-                    groups.append((src, [idx]))
-            parts = []
+                    groups.append((key, [item]))
+            parts = []                   # host runs upload in .concat
             for src, idxs in groups:
-                leaves, treedef = jax.tree.flatten(src)
-                if idxs is None:
-                    for a in leaves:
-                        if not isinstance(a, jax.Array):     # host row
-                            n_up += 1
-                            n_bytes += a.nbytes
-                    parts.append(treedef.unflatten([a[None] for a in leaves]))
+                if src is _HOST_RUN:
+                    parts.append(None)
+                elif idxs is None:
+                    parts.append(jax.tree.map(lambda a: a[None], src))
                 else:
+                    leaves, treedef = jax.tree.flatten(src)
                     arr = jnp.asarray(idxs)
                     n_up += 1
                     n_bytes += arr.nbytes
                     n_takes += len(leaves)
                     parts.append(treedef.unflatten([a[arr] for a in leaves]))
         with tracer.span('serve.assemble.concat', track='host'):
+            for i, (src, rows) in enumerate(groups):
+                if src is _HOST_RUN:
+                    parts[i], nb = _upload_rows(
+                        rows, slots if len(groups) == 1 else len(rows))
+                    n_up += len(jax.tree.leaves(parts[i]))
+                    n_bytes += nb
             batch = (parts[0] if len(parts) == 1
                      else jax.tree.map(lambda *ps: jnp.concatenate(ps),
                                        *parts))

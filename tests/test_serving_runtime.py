@@ -282,16 +282,20 @@ def test_metrics_percentiles_and_occupancy():
 
 
 def test_gather_rows_counts_uploads_and_takes():
-    """What ``_gather_rows`` issues: one upload per fresh host row, none
-    for a row already on the device, and per survivor source batch one
-    index upload and one take per leaf."""
+    """What ``_gather_rows`` issues: one upload for a run of fresh host
+    rows (a batch of them padded on the host, so its bytes are the whole
+    batch's), none for a row already on the device, and per survivor
+    source batch one index upload and one take per leaf."""
     from repro.serving.scheduler import _gather_rows
     rows = np.random.default_rng(0).normal(size=(SLOTS, 4, 4, 3)).astype(
         np.float32)
     batch, tr = _gather_rows([(r, None) for r in rows], SLOTS)
-    assert tr == {'n_uploads': SLOTS, 'upload_bytes': rows.nbytes,
+    assert tr == {'n_uploads': 1, 'upload_bytes': rows.nbytes,
                   'n_takes': 0}
     np.testing.assert_array_equal(np.asarray(batch), rows)
+    _, tr = _gather_rows([(rows[0], None)], SLOTS)
+    assert tr == {'n_uploads': 1, 'upload_bytes': rows.nbytes,
+                  'n_takes': 0}
     _, tr = _gather_rows([(jnp.asarray(rows[0]), None)], SLOTS)
     assert tr == {'n_uploads': 0, 'upload_bytes': 0, 'n_takes': 0}
     a, b = jnp.asarray(rows), QAct(jnp.asarray(rows, jnp.int8), 0.5)
@@ -308,10 +312,40 @@ def test_gather_rows_counts_uploads_and_takes():
     assert tr == {'n_uploads': 1, 'upload_bytes': 2 * 4, 'n_takes': 1}
 
 
+@pytest.mark.parametrize('kinds', ['h' * SLOTS, 'hhh', 'hhdh', 'dhhd',
+                                   'htthh'])
+def test_gather_rows_uploads_each_host_run_once(kinds):
+    """Row ``i`` of the batch is source ``i``: a fresh host row ('h'), a
+    fresh device row ('d') or a survivor take from one device batch
+    ('t').  Each run of host rows uploads once; a batch of host rows
+    alone comes back zero-padded to ``slots`` from the host, bit for bit
+    ``np.stack`` of its rows; mixed batches keep the sources' order."""
+    from repro.serving.scheduler import _gather_rows
+    rows = np.random.default_rng(1).normal(size=(SLOTS, 4, 4, 3)).astype(
+        np.float32)
+    dev = jnp.asarray(rows)
+    src = {'h': lambda i: (rows[i], None),
+           'd': lambda i: (jnp.asarray(rows[i]), None),
+           't': lambda i: (dev, i)}
+    batch, tr = _gather_rows([src[k](i) for i, k in enumerate(kinds)],
+                             SLOTS)
+    assert isinstance(batch, jax.Array) and batch.shape == rows.shape
+    out = np.asarray(batch)
+    n = len(kinds)
+    np.testing.assert_array_equal(out[:n], np.stack(rows[:n]))
+    assert not out[n:].any()
+    runs = [k for i, k in enumerate(kinds) if i == 0 or kinds[i - 1] != k]
+    host_rows = SLOTS if set(kinds) == {'h'} else kinds.count('h')
+    assert tr == {'n_uploads': runs.count('h') + runs.count('t'),
+                  'upload_bytes': host_rows * rows[0].nbytes
+                  + kinds.count('t') * 4,
+                  'n_takes': runs.count('t')}
+
+
 def test_scheduler_transfer_counters_follow_batch_composition(exported):
-    """Fresh host rows upload once each at segment 0; with no exits every
-    later batch gathers one source batch's survivors: one index upload
-    and one take.  The totals ride on the run's metrics."""
+    """A segment-0 batch of fresh host rows uploads once; with no exits
+    every later batch gathers one source batch's survivors: one index
+    upload and one take.  The totals ride on the run's metrics."""
     model, _ = exported
     rows = np.asarray(jax.random.normal(jax.random.key(9),
                                         (2 * SLOTS, 32, 32, 3)))
@@ -322,9 +356,28 @@ def test_scheduler_transfer_counters_follow_batch_composition(exported):
     later = 2 * (model.n_stages - 1)
     s = met.summary()
     assert s['n_batches'] == {str(k): 2 for k in range(model.n_stages)}
-    assert s['n_uploads'] == 2 * SLOTS + later
+    assert s['n_uploads'] == 2 + later
     assert s['upload_bytes'] == rows.nbytes + later * SLOTS * 4
     assert s['n_takes'] == later
+
+
+def test_scheduler_host_rows_match_request_alone_oracle(exported):
+    """Fresh host ``numpy`` rows, stacked and uploaded per batch, answer
+    bit for bit as the monolithic ``fn_exits`` on each request alone,
+    partial final batch included."""
+    model, _ = exported
+    x8 = jax.random.normal(jax.random.key(3), (SLOTS, 32, 32, 3))
+    thr = calibrate_exit_threshold(model, x8)
+    reqs = [Request(r.rid, np.asarray(r.x), r.t_arrival)
+            for r in _trace(2 * SLOTS + 3)]
+    comp, _ = ContinuousBatchScheduler(
+        model, slots=SLOTS, threshold=thr,
+        stage_costs=[1e-3] * model.n_stages).run_trace(reqs)
+    assert len(comp) == len(reqs)
+    for r in reqs:
+        stage, ans = _oracle(model, r.x, thr)
+        assert comp[r.rid].exit_stage == stage
+        np.testing.assert_array_equal(comp[r.rid].logits, ans)
 
 
 def test_latency_splits_into_queue_wait_and_execute(exported):
