@@ -14,7 +14,10 @@ test can hand-build a trace.
   ``bench.`` label what the host was doing.
 
 Busy time is the union of a chip's op intervals clipped to the window,
-averaged over the chips; the idle share is 1 - busy / window.
+averaged over the chips; the idle share is 1 - busy / window.  Each op
+name's own time (``op_s``) is the sum of its clipped intervals over every
+chip, overlaps included, so a reader can hold one kernel's time against
+its least time.
 """
 from __future__ import annotations
 
@@ -109,9 +112,12 @@ def reduce_trace(device: dict, host: list, top: int = 10) -> dict:
     """Busy and idle time of the chips in the window, and a breakdown.
 
     Returns ``busy_s`` (mean over chips), ``window_s``, ``idle_share``,
-    ``n_ops``, ``device_ops`` (the ``top`` op names by device time in the
-    window, ``[[name, seconds]]``) and ``idle_gaps`` (idle seconds summed by
-    the host span the gap fell in, the ``top`` largest)."""
+    ``n_ops``, ``op_s`` (``{name: seconds}``: every op name's device time
+    in the window, summed over chips, for the per-layer readers),
+    ``device_ops`` (the ``top`` of ``op_s`` as ``[[name, seconds]]``) and
+    ``idle_gaps`` (idle seconds summed by the host span the gap fell in,
+    the ``top`` largest).  The result line's ``breakdown`` carries
+    ``device_ops`` and ``idle_gaps`` only."""
     lo, hi = window_bounds(host)
     if not device:
         raise ValueError('the trace holds no device plane')
@@ -139,5 +145,5 @@ def reduce_trace(device: dict, host: list, top: int = 10) -> dict:
 
     return {'busy_s': busy_s, 'window_s': window_s,
             'idle_share': 1.0 - busy_s / window_s if window_s > 0 else None,
-            'n_ops': n_ops, 'device_ops': top_of(by_op),
+            'n_ops': n_ops, 'op_s': by_op, 'device_ops': top_of(by_op),
             'idle_gaps': top_of(gaps)}

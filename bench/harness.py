@@ -4,6 +4,14 @@
 call ``run()`` directly with a tiny configuration on the CPU
 (``require_chip=False``).
 
+Configuration file: the keys in ``HARNESS_KEYS`` are the harness's own
+(sizes of the run, the reference file, the record of cuts and
+departures); every other key is an architecture key and goes to the
+program's ``CNNConfig`` field of that name (``program_config``), and the
+benchmark's work count reads the same keys (bench/workcount.py).  A key
+that is neither is refused with ``ValueError`` before any weights are
+made.
+
 Set-up (``setup_s``, from process start to the first timed request):
 random weights from the seed in one jitted call (the reference file's
 ``init``), the image pool in another, the int8-resident export
@@ -46,6 +54,11 @@ BENCH = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH)
 MANIFEST = os.path.join(ROOT, 'BENCHMARK.json')
 BACKEND_COMPILE_EVENT = '/jax/core/compile/backend_compile_duration'
+# configuration-file keys the harness reads or keeps for the reader; every
+# other key is an architecture key and must name a ``CNNConfig`` field
+HARNESS_KEYS = frozenset({
+    'source', 'image_size', 'slots', 'calibration_images', 'reference',
+    'reduced', 'departures', 'deployment', 'assumed'})
 
 
 class NoChip(RuntimeError):
@@ -98,14 +111,34 @@ def key_from_seed(seed: int):
 
 
 def program_config(cfg, bits):
+    """The program's ``CNNConfig`` for a configuration file's dict: every
+    key that names a ``CNNConfig`` field is passed on (lists as tuples),
+    with ``w_bits``/``a_bits`` set to ``bits``.  A key that is neither a
+    field nor one of ``HARNESS_KEYS`` is a model the program cannot build:
+    ``ValueError``, never a silently different model.  So is a mobilenet
+    that the program and the work count would read differently: one with
+    no expansion key (the program would take its default), or with
+    ``expand_ratio`` 1 (the program still builds the expand conv that the
+    count leaves out at t = 1)."""
+    import dataclasses
     from repro.configs.cnn import CNNConfig
-    return CNNConfig(name=cfg['name'], kind=cfg['kind'],
-                     num_classes=cfg['num_classes'],
-                     in_channels=cfg['in_channels'],
-                     stage_blocks=tuple(cfg['stage_blocks']),
-                     stage_widths=tuple(cfg['stage_widths']),
-                     w_bits=bits, a_bits=bits,
-                     exit_stages=tuple(cfg['exit_stages']))
+    fields = {f.name for f in dataclasses.fields(CNNConfig)}
+    unknown = sorted(set(cfg) - fields - HARNESS_KEYS)
+    if unknown:
+        raise ValueError(f'configuration {cfg.get("name")!r}: the program '
+                         f'has no field {", ".join(map(repr, unknown))}')
+    if cfg.get('kind') == 'mobilenet':
+        if 'stage_expand' not in cfg and 'expand_ratio' not in cfg:
+            raise ValueError(f'mobilenet configuration {cfg.get("name")!r} '
+                             f'states neither stage_expand nor expand_ratio')
+        if cfg.get('expand_ratio') == 1:
+            raise ValueError(f'configuration {cfg.get("name")!r}: '
+                             f'expand_ratio 1, but the program builds an '
+                             f'expand conv at t = 1 and the work count '
+                             f'does not')
+    kw = {k: tuple(v) if isinstance(v, list) else v
+          for k, v in cfg.items() if k in fields}
+    return CNNConfig(**dict(kw, w_bits=bits, a_bits=bits))
 
 
 def exit_threshold(model, pool, slots, rule):
@@ -252,7 +285,9 @@ def reference_errors(sample, forward, params, pool, slots) -> np.ndarray:
 def per_layer_metrics(manifest, workload, reported, ctx):
     """Per-layer metrics that belong to this cell, each read by its own
     reader ``bench/metrics/<name>.py``; a reader that finds nothing
-    returns None and its metric is left out."""
+    returns None and its metric is left out.  ``ctx.trace`` is
+    ``devtrace.reduce_trace``'s dict in a traced run (``op_s`` holds every
+    device op's seconds), None otherwise."""
     out = {}
     for m in manifest['per_layer']:
         if workload not in m['workloads']:
@@ -303,6 +338,9 @@ def run(workload, seed, seconds, trace, *, t_start, spec=None,
         # programs in the persistent cache too, so that only a checkout's
         # first run builds them
         jax.config.update('jax_persistent_cache_min_compile_time_secs', 0.0)
+    # a configuration the program cannot express fails here, before any
+    # weights are made
+    pcfg = program_config(cfg, bits or cfg['w_bits'])
     counter = CompileCounter()
     ref = load_module(os.path.join(BENCH, cfg['reference']),
                       'bench_ref_' + cfg['name'].replace('-', '_'))
@@ -323,8 +361,7 @@ def run(workload, seed, seconds, trace, *, t_start, spec=None,
     order = rng.permutation(n_pool)
 
     tracer = Tracer()
-    model = export_cnn(params, program_config(cfg, bits or cfg['w_bits']),
-                       calibrate=calib, tracer=tracer)
+    model = export_cnn(params, pcfg, calibrate=calib, tracer=tracer)
     del images, calib
     if model.n_stages != workcount.n_segments(cfg):
         raise RuntimeError(f'export has {model.n_stages} segments, the '

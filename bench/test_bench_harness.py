@@ -164,6 +164,25 @@ def test_trace_reduction_busy_union_idle_and_window():
     assert sum(gaps.values()) == pytest.approx(0.1 - busy_ms * 1e-3)
 
 
+def test_trace_reduction_op_s_holds_every_op():
+    host = [('bench.window', _ns(10), _ns(110))]
+    # 14 op names on two chips: more than the top 10 of device_ops
+    ops0 = [(f'op{i}', _ns(5 + 7 * i), _ns(12 + 7 * i)) for i in range(14)]
+    ops1 = [(f'op{i}', _ns(20), _ns(21 + i)) for i in range(14)]
+    red = devtrace.reduce_trace({'/device:TPU:0': ops0,
+                                 '/device:TPU:1': ops1}, host)
+    assert len(red['device_ops']) == 10
+    assert set(red['op_s']) == {f'op{i}' for i in range(14)}
+    for name, sec in red['device_ops']:
+        assert red['op_s'][name] == sec
+    clipped = sum(min(t1, _ns(110)) - max(t0, _ns(10))
+                  for _, t0, t1 in ops0 + ops1
+                  if t1 > _ns(10) and t0 < _ns(110)) * 1e-9
+    assert sum(red['op_s'].values()) == pytest.approx(clipped)
+    # op0 starts before the window: 5-12 ms on chip 0 counts 10-12
+    assert red['op_s']['op0'] == pytest.approx(0.002 + 0.001)
+
+
 def test_trace_reduction_averages_chips_and_needs_one_window():
     host = [('bench.window', 0, _ns(100))]
     red = devtrace.reduce_trace({'/device:TPU:0': [('a', 0, _ns(50))],
@@ -185,6 +204,64 @@ def test_merge_and_labels():
         devtrace.NO_SPAN]
 
 
+# ------------------------------------------- configuration to program
+
+
+def _nine_key_config(cfg, bits):
+    """The construction the harness used before it passed every field."""
+    from repro.configs.cnn import CNNConfig
+    return CNNConfig(name=cfg['name'], kind=cfg['kind'],
+                     num_classes=cfg['num_classes'],
+                     in_channels=cfg['in_channels'],
+                     stage_blocks=tuple(cfg['stage_blocks']),
+                     stage_widths=tuple(cfg['stage_widths']),
+                     w_bits=bits, a_bits=bits,
+                     exit_stages=tuple(cfg['exit_stages']))
+
+
+@pytest.mark.parametrize('name,bits', [('resnet34-cifar', 8),
+                                       ('vgg19-cifar', 8),
+                                       ('resnet34-cifar', 4)])
+def test_program_config_of_the_shipped_configs(name, bits):
+    cfg = harness.load_json(os.path.join(HERE, 'configs', name + '.json'))
+    assert harness.program_config(cfg, bits) == _nine_key_config(cfg, bits)
+
+
+@pytest.mark.parametrize('key,value', [('expand_ratio', 6),
+                                       ('stage_widths', [8, 16, 24])])
+def test_program_config_passes_every_field(key, value):
+    cfg = _tiny('mobilenet')
+    cfg[key] = value
+    got = getattr(harness.program_config(cfg, 8), key)
+    assert got == (tuple(value) if isinstance(value, list) else value)
+
+
+def test_unknown_config_key_fails_before_any_weights(monkeypatch):
+    cell, spec = _spec('resnet', 'exit-backlog')
+    spec['config']['head_widht'] = 1280
+
+    def no_reference(*a, **k):
+        raise AssertionError('the reference was loaded')
+    monkeypatch.setattr(harness, 'load_module', no_reference)
+    with pytest.raises(ValueError, match="no field 'head_widht'"):
+        harness.run(cell, SEED, 0.2, False, t_start=time.perf_counter(),
+                    spec=spec, require_chip=False)
+
+
+@pytest.mark.parametrize('change', [{'expand_ratio': None},
+                                    {'expand_ratio': 1}])
+def test_program_config_refuses_a_mobilenet_the_count_reads_otherwise(
+        change):
+    cfg = _tiny('mobilenet')
+    for key, value in change.items():
+        if value is None:
+            del cfg[key]
+        else:
+            cfg[key] = value
+    with pytest.raises(ValueError, match='expand'):
+        harness.program_config(cfg, 8)
+
+
 # ------------------------------------------------------ op arithmetic
 
 
@@ -194,18 +271,26 @@ def _tiny(kind):
     cfg.update(kind=kind, stage_blocks=[1, 2, 1], stage_widths=[8, 16, 32],
                image_size=16, exit_stages=[0, 1], slots=8,
                calibration_images=16)
+    if kind == 'mobilenet':
+        cfg['expand_ratio'] = 4
     return cfg
 
 
-@pytest.mark.parametrize('kind', ['resnet', 'vgg'])
+@pytest.mark.parametrize('kind', ['resnet', 'vgg', 'mobilenet'])
 def test_layer_macs_match_the_programs_layer_plan(kind):
     import jax
     from repro.core.export import export_cnn
+    from repro.models.cnn import init_cnn
     cfg = _tiny(kind)
-    ref = harness.load_module(os.path.join(HERE, cfg['reference']), 'ref')
-    params = ref.init(jax.random.key(1), cfg)
+    pcfg = harness.program_config(cfg, 8)
+    if kind == 'mobilenet':     # the reference file has no mobilenet
+        params = init_cnn(jax.random.key(1), pcfg)
+    else:
+        ref = harness.load_module(os.path.join(HERE, cfg['reference']),
+                                  'ref')
+        params = ref.init(jax.random.key(1), cfg)
     x = jax.random.normal(jax.random.key(2), (4, 16, 16, 3))
-    model = export_cnn(params, harness.program_config(cfg, 8), calibrate=x)
+    model = export_cnn(params, pcfg, calibrate=x)
     plan = {n: e['macs'] for n, e in model.plan.layers.items()}
     mine = {lyr['name']: lyr['macs'] for lyr in workcount.layers(cfg)}
     assert mine == plan
@@ -213,6 +298,80 @@ def test_layer_macs_match_the_programs_layer_plan(kind):
         e = model.plan.layers[lyr['name']]
         assert lyr['in_elems'] == math.prod(e['in_shape'][1:])
         assert lyr['out_elems'] == math.prod(e['out_shape'][1:])
+
+
+# per-segment MACs, conv and fc weights, and layers of each shipped
+# configuration, as counted since the benchmark began
+SHIPPED_COUNTS = {
+    'resnet34-cifar': ([513_475_840, 645_927_936], 21_266_368, 38),
+    'vgg19-cifar': ([266_013_184, 169_874_432], 20_063_424, 19),
+}
+
+
+@pytest.mark.parametrize('name', sorted(SHIPPED_COUNTS))
+def test_layer_counts_of_the_shipped_configs(name):
+    cfg = harness.load_json(os.path.join(HERE, 'configs', name + '.json'))
+    seg_macs, weights, n_layers = SHIPPED_COUNTS[name]
+    lyrs = workcount.layers(cfg)
+    assert [sum(lyr['macs'] for lyr in lyrs if lyr['seg'] == s)
+            for s in range(workcount.n_segments(cfg))] == seg_macs
+    assert sum(lyr['w_elems'] for lyr in lyrs) == weights
+    assert len(lyrs) == n_layers
+    assert {lyr['kind'] for lyr in lyrs} == {'conv', 'fc'}
+
+
+# the paper's inverted-residual block (Sandler et al. 2018, Table 2: the
+# (t, c, n, s) table, a 32-wide stem, the 320 -> 1280 1x1 conv ahead of
+# the pool) at the CIFAR strides of kuangliu/pytorch-cifar
+# models/mobilenetv2.py.  Two departures from kuangliu's file: no expand
+# conv at t = 1 (kuangliu's Block always has its 1x1 conv1) and no 1x1
+# shortcut convs (kuangliu projects where a stride-1 block changes width)
+MOBILENETV2_CIFAR = {
+    'name': 'mobilenetv2-cifar', 'kind': 'mobilenet', 'in_channels': 3,
+    'image_size': 32, 'num_classes': 10, 'exit_stages': [],
+    'stem_width': 32, 'stage_expand': [1, 6, 6, 6, 6, 6, 6],
+    'stage_widths': [16, 24, 32, 64, 96, 160, 320],
+    'stage_blocks': [1, 2, 3, 4, 3, 3, 1],
+    'stage_strides': [1, 1, 2, 2, 1, 2, 1], 'head_width': 1280,
+}
+
+
+def test_mobilenetv2_cifar_counts():
+    cfg = dict(MOBILENETV2_CIFAR)
+    lyrs = workcount.layers(cfg)
+    assert sum(lyr['macs'] for lyr in lyrs) == 87_976_448
+    assert sum(lyr['w_elems'] for lyr in lyrs) == 2_202_560
+    dw = [lyr for lyr in lyrs if lyr['kind'] == 'depthwise']
+    assert len(dw) == 17
+    assert sum(lyr['macs'] for lyr in dw) == 5_879_808
+    names = [lyr['name'] for lyr in lyrs]
+    assert 's0b0.expand' not in names          # t = 1: no expand conv
+    assert names[:4] == ['stem', 's0b0.dw', 's0b0.project', 's1b0.expand']
+    assert names[-2:] == ['head_conv', 'head']
+    by = {lyr['name']: lyr for lyr in lyrs}
+    assert by['stem']['out_elems'] == 32 * 32 * 32
+    assert by['s2b0.dw']['in_elems'] == 32 * 32 * 144     # stride 2 here
+    assert by['s2b0.dw']['out_elems'] == 16 * 16 * 144
+    assert by['s6b0.dw']['out_elems'] == 4 * 4 * 960
+    assert by['head_conv']['macs'] == 4 * 4 * 320 * 1280
+    assert by['head']['macs'] == 1280 * 10
+    peaks = workcount.load_peaks('TPU v5 lite')
+    assert workcount.segment_ops(cfg, 0, 1) == 2 * 87_976_448
+    # every layer is bound by HBM bytes at a 64-image batch, so the
+    # segment's least time is the sum of its layers' byte times
+    byte_s = {}
+    for lyr in lyrs:
+        ops, nbytes = workcount.layer_ops_bytes(lyr, 64)
+        assert ops / peaks['int8_ops_per_s'] \
+            < nbytes / peaks['hbm_bytes_per_s'], lyr['name']
+        byte_s[lyr['name']] = nbytes / peaks['hbm_bytes_per_s']
+    assert workcount.segment_least_s(cfg, 0, 64, peaks) \
+        == pytest.approx(sum(byte_s.values()))
+    del cfg['stage_expand']
+    with pytest.raises(KeyError, match='expand'):
+        workcount.layers(cfg)
+    cfg['expand_ratio'] = 6     # uniform: s0 gains its expand conv
+    assert 's0b0.expand' in [lyr['name'] for lyr in workcount.layers(cfg)]
 
 
 def test_resnet34_segment_split():
